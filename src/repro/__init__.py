@@ -1,13 +1,5 @@
 """repro: jax_pallas reproduction of "Practically and Theoretically Efficient
 Garbage Collection for Multiversioning".
 
-Importing any ``repro.*`` module installs the forward-compat aliases in
-:mod:`repro._jax_compat` so code written against the current jax API
-(``jax.set_mesh``, ``jax.shard_map``, ``jax.P``, ``jax.sharding.AxisType``,
-``jax.make_mesh(..., axis_types=...)``) also runs on the 0.4.x jax baked into
-this container.  Importing jax here does NOT initialize backends — device
-state is still created lazily, after XLA_FLAGS overrides (see launch/dryrun).
+Written for the installed jax (0.9.0).  Importing ``repro`` touches no device.
 """
-from repro import _jax_compat
-
-_jax_compat.install()

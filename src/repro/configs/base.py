@@ -166,10 +166,11 @@ class RunConfig:
     versions_per_slot: int = 8
     reader_lanes: int = 16
     page_size: int = 64
-    # dispatch GC sweeps / snapshot reads to the fused Pallas kernels
-    # (kernel_interpret=True validates them on CPU; set False on TPU)
-    use_kernel: bool = False
-    kernel_interpret: bool = True
+    # GC sweeps / snapshot reads on the fused Pallas kernels; None = by
+    # platform (core.telemetry.resolve_kernel: compiled on a TPU, lax path
+    # elsewhere)
+    use_kernel: Optional[bool] = None
+    kernel_interpret: Optional[bool] = None
     # retire-ring capacity for the RT policies; 0 = sized from the batch.
     # Undersizing it drops retire records (surfaced as ``dropped_retires``
     # in the engine step stats) — DL-RT can never reclaim a dropped version.

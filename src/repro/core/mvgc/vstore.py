@@ -49,7 +49,7 @@ from repro.core.mvgc import announce as ann
 from repro.core.mvgc import pool, rangetracker as rt
 from repro.core.mvgc.needed import needed_intervals, sort_announcements
 from repro.core.mvgc.pool import EMPTY, TS_MAX, VersionStore
-from repro.core.telemetry import GCConfig, PressureSignal
+from repro.core.telemetry import GCConfig, PressureSignal, resolve_kernel
 from repro.kernels.compact import ops as compact_ops
 from repro.kernels.version_search import ops as search_ops
 
@@ -104,8 +104,8 @@ def write_step(
     payloads: jax.Array,   # i32[K] new payload handles
     mask: jax.Array,       # bool[K]
     policy: str = "slrt",
-    use_kernel: bool = False,
-    interpret: bool = True,
+    use_kernel: Optional[bool] = None,
+    interpret: Optional[bool] = None,
     extra_pins: Optional[jax.Array] = None,
 ) -> Tuple[MVState, jax.Array, jax.Array]:
     """One bulk-synchronous update step: tick the clock, append versions,
@@ -175,13 +175,16 @@ def snapshot_read(
     state: MVState,
     slot_ids: jax.Array,
     t: jax.Array,
-    use_kernel: bool = False,
-    interpret: bool = True,
+    use_kernel: Optional[bool] = None,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """rtx read: latest payload at-or-before t per slot (search(t)).
 
     ``use_kernel`` dispatches to the Pallas version_search kernel (interpret
-    mode validates it on CPU); the default is the lax masked-argmax path."""
+    mode validates it on CPU); unset, the platform decides
+    (:func:`repro.core.telemetry.resolve_kernel`): the kernel on a TPU, the
+    lax masked-argmax path elsewhere."""
+    use_kernel, interpret = resolve_kernel(use_kernel, interpret)
     if use_kernel:
         t_b = jnp.broadcast_to(jnp.asarray(t, jnp.int32), slot_ids.shape)
         return search_ops.search(
@@ -196,8 +199,8 @@ def snapshot_gather(
     slot_ids: jax.Array,  # i32[B]
     t: jax.Array,         # i32[] or i32[B] pinned timestamp(s)
     values: jax.Array,    # i32[T, M] payload-indexed value rows
-    use_kernel: bool = False,
-    interpret: bool = True,
+    use_kernel: Optional[bool] = None,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Fused rtx read: resolve search(t) per slot AND gather the value rows
     the resolved payloads index — one launch on the kernel path, one fused
@@ -292,8 +295,8 @@ def gc_step(
     policy: str = "slrt",
     force: bool = False,
     flush_fraction: float = 0.5,
-    use_kernel: bool = False,
-    interpret: bool = True,
+    use_kernel: Optional[bool] = None,
+    interpret: Optional[bool] = None,
     extra_pins: Optional[jax.Array] = None,
     ckpt_max: Optional[jax.Array] = None,
 ) -> Tuple[MVState, jax.Array]:
@@ -321,8 +324,8 @@ def _policy_gc_step(
     policy: str = "slrt",
     force: bool = False,
     flush_fraction: float = 0.5,
-    use_kernel: bool = False,
-    interpret: bool = True,
+    use_kernel: Optional[bool] = None,
+    interpret: Optional[bool] = None,
     extra_pins: Optional[jax.Array] = None,
 ) -> Tuple[MVState, jax.Array]:
     """The per-policy collection pass proper (no checkpoint post-pass)."""
@@ -383,7 +386,9 @@ def _policy_gc_step(
 
 
 def _sweep_all_needed(
-    state: MVState, use_kernel: bool = False, interpret: bool = True,
+    state: MVState,
+    use_kernel: Optional[bool] = None,
+    interpret: Optional[bool] = None,
     extra_pins: Optional[jax.Array] = None,
 ) -> Tuple[MVState, jax.Array]:
     """Full-store needed-sweep: the fused compact primitive over every slab
@@ -404,15 +409,17 @@ def _sweep_slots(
     state: MVState,
     slot_ids: jax.Array,
     mask: jax.Array,
-    use_kernel: bool = False,
-    interpret: bool = True,
+    use_kernel: Optional[bool] = None,
+    interpret: Optional[bool] = None,
     extra_pins: Optional[jax.Array] = None,
 ) -> Tuple[MVState, jax.Array]:
     """needed-sweep restricted to the given slots (steam / slrt locality).
 
     ``use_kernel`` dispatches the gathered rows through the fused Pallas
     compaction kernel; otherwise the lax searchsorted form runs (the two are
-    differentially tested in tests/mvgc/test_vstore.py)."""
+    differentially tested in tests/mvgc/test_vstore.py); unset, the platform
+    decides (:func:`repro.core.telemetry.resolve_kernel`)."""
+    use_kernel, interpret = resolve_kernel(use_kernel, interpret)
     A = _ann_scan(state, extra_pins)
     rows_ts = state.store.ts[slot_ids]
     rows_succ = state.store.succ[slot_ids]
@@ -496,8 +503,8 @@ def reclaim_on_pressure(
     hot_keys: jax.Array,  # i32[K] hot slot ids (-1 = inert lane), cf. hot_slots()
     deficit: jax.Array,   # i32[]  versions to free (capacity_gate().deficit)
     policy: str = "slrt",
-    use_kernel: bool = False,
-    interpret: bool = True,
+    use_kernel: Optional[bool] = None,
+    interpret: Optional[bool] = None,
     extra_pins: Optional[jax.Array] = None,
     ckpt_max: Optional[jax.Array] = None,
 ) -> Tuple[MVState, jax.Array, jax.Array]:
@@ -522,8 +529,8 @@ def _policy_reclaim(
     hot_keys: jax.Array,
     deficit: jax.Array,
     policy: str = "slrt",
-    use_kernel: bool = False,
-    interpret: bool = True,
+    use_kernel: Optional[bool] = None,
+    interpret: Optional[bool] = None,
     extra_pins: Optional[jax.Array] = None,
 ) -> Tuple[MVState, jax.Array, jax.Array]:
     """Synchronous pressure response: run the policy's sweep over the hot

@@ -32,7 +32,7 @@ from __future__ import annotations
 import dataclasses
 import math
 import warnings
-from typing import Any, Dict, NamedTuple, Optional
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 
 class PressureSignal(NamedTuple):
@@ -147,8 +147,9 @@ class GCConfig:
     versions_per_slot: int = 8      # descriptor slab depth
     reader_lanes: int = 8           # announcement-board lanes
     ring_capacity: int = 0          # retire ring; 0 = sized from the store
-    use_kernel: bool = False        # dispatch sweeps to the Pallas kernels
-    kernel_interpret: bool = True   # interpret mode (CPU validation)
+    # GC kernel path; None = by platform (see resolve_kernel)
+    use_kernel: Optional[bool] = None        # Pallas kernels vs the lax path
+    kernel_interpret: Optional[bool] = None  # Pallas interpreter (CPU only)
     slab_watermark: float = 0.75    # vstore capacity_gate slab threshold
     ring_watermark: float = 0.5     # vstore capacity_gate ring threshold
     page_watermark: float = 0.25    # paged-pool free-fraction threshold
@@ -160,13 +161,38 @@ class GCConfig:
     stale_after_s: float = math.inf
 
     def kernel_kwargs(self) -> Dict[str, bool]:
-        """The (use_kernel, interpret) pair most vstore/paged calls take."""
-        return {"use_kernel": self.use_kernel,
-                "interpret": self.kernel_interpret}
+        """The (use_kernel, interpret) pair most vstore/paged calls take,
+        resolved for the platform by :func:`resolve_kernel`."""
+        use_kernel, interpret = resolve_kernel(self.use_kernel,
+                                               self.kernel_interpret)
+        return {"use_kernel": use_kernel, "interpret": interpret}
 
     def replace(self, **kw) -> "GCConfig":
         """``dataclasses.replace`` shorthand."""
         return dataclasses.replace(self, **kw)
+
+
+def resolve_kernel(use_kernel: Optional[bool] = None,
+                   interpret: Optional[bool] = None) -> Tuple[bool, bool]:
+    """The one place that picks the GC kernels' path: ``(use_kernel,
+    interpret)`` with every ``None`` filled in from ``jax.default_backend()``.
+
+    On a TPU an unset choice gives the compiled Pallas kernels; elsewhere it
+    gives the lax reference path, and the Pallas interpreter if a caller
+    asks for the kernels.  Explicit values are kept, except that a kernel
+    interpreted on a TPU raises: a chip run never falls back to it."""
+    import jax
+
+    on_tpu = jax.default_backend() == "tpu"
+    if use_kernel is None:
+        use_kernel = on_tpu
+    if interpret is None:
+        interpret = not on_tpu
+    if on_tpu and use_kernel and interpret:
+        raise ValueError(
+            "interpret=True on a TPU: the Pallas interpreter is for CPU "
+            "validation; leave interpret unset to run the compiled kernels")
+    return bool(use_kernel), bool(interpret)
 
 
 def resolve_gc_config(gc: Optional[GCConfig], where: str,

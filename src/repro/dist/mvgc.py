@@ -103,15 +103,45 @@ def global_lwm(contrib: jax.Array, ring=None) -> jax.Array:
 # ---------------------------------------------------------------------------
 # sharded serving engine
 # ---------------------------------------------------------------------------
+def _per_shard(fn, mesh, axis: str):
+    """``jit`` of ``fn`` vmapped over the hosts each device holds.  Every
+    argument and result is ``[H, ...]``-leading and split over ``axis`` by
+    a ``shard_map``, so each device runs the single-host op on its own
+    shards and nothing crosses devices; the GC kernels inside could not be
+    split by XLA's partitioner anyway."""
+    spec = jax.sharding.PartitionSpec(axis)
+    return jax.jit(jax.shard_map(jax.vmap(fn), mesh=mesh, in_specs=spec,
+                                 out_specs=spec, check_vma=False))
+
+
+def _host_slice(x: jax.Array, host: int) -> jax.Array:
+    """``x[host]`` for a host-stacked leaf, read from the one device that
+    holds that host's shard.  Indexing the sharded array itself would leave
+    the slice laid out over the whole mesh, and a Pallas kernel fed from it
+    would have to be partitioned, which Mosaic cannot do."""
+    for shard in x.addressable_shards:
+        rows = range(x.shape[0])[shard.index[0]]
+        if host in rows:
+            return shard.data[host - rows.start]
+    raise ValueError(f"host {host} is not held by this process")
+
+
+def _per_host(failed: jax.Array) -> np.ndarray:
+    """i32[H]: failed lanes per host, the deficit each shard must chase."""
+    return np.asarray(failed).sum(axis=1).astype(np.int32)
+
+
 class ShardedPagedKVEngine:
     """Multi-host paged-KV serving with global-LWM reclamation.
 
     ``hosts`` logical shards, each owning ``num_seqs`` sequences and
     ``num_pages`` pool pages, stacked along a leading ``[H]`` dim and placed
-    over ``mesh`` (default :func:`repro.launch.mesh.make_gc_mesh`; when the
-    machine has fewer devices than hosts the stack stays unsharded and every
-    reduction degrades gracefully — the protocol is placement-independent).
-    All batched entry points take ``[H, ...]``-leading arguments.
+    over ``mesh`` (default :func:`repro.launch.mesh.make_gc_mesh`).  On a
+    mesh of several devices the stack is built sharded, ``hosts / devices``
+    shards per device, and a host count the mesh does not divide raises; on
+    a one-device mesh it stays unsharded and the LWM reduction is a plain
+    ``min`` — the protocol is placement-independent.  All batched entry
+    points take ``[H, ...]``-leading arguments.
 
     Every GC-bearing step first refreshes the global LWM (contributions ->
     staleness aging -> ring-min) and threads it through the shard ops as
@@ -135,17 +165,27 @@ class ShardedPagedKVEngine:
         axis = mesh.axis_names[0]
         n = mesh.shape[axis]
 
-        base = paged.make_paged_kv(num_seqs, num_pages, page_size,
-                                   max_pages_per_seq, kv_heads, head_dim,
-                                   gc=cfg, dtype=dtype)
-        st = stack_states(base, hosts)
-        if n > 1 and hosts % n == 0:
-            st = jax.device_put(st, host_stacked_sharding(st, mesh, axis))
+        def build():
+            return stack_states(
+                paged.make_paged_kv(num_seqs, num_pages, page_size,
+                                    max_pages_per_seq, kv_heads, head_dim,
+                                    gc=cfg, dtype=dtype), hosts)
+
+        if n > 1:
+            if hosts % n:
+                raise ValueError(
+                    f"{hosts} hosts do not divide over the {n}-device mesh "
+                    f"axis {axis!r}")
+            # built in place, shard by shard: the whole stack would not fit
+            # on one device
+            shardings = host_stacked_sharding(jax.eval_shape(build), mesh,
+                                              axis)
+            self.st = jax.jit(build, out_shardings=shardings)()
             self._ring = jax.jit(make_ring_all_reduce(mesh, axis,
                                                       reduce="min"))
         else:
+            self.st = build()
             self._ring = None
-        self.st = st
 
         kern = cfg.kernel_kwargs()
 
@@ -170,15 +210,16 @@ class ShardedPagedKVEngine:
         def _evict(s, ckpt, pins):
             return paged.evict_checkpointed(s, ckpt, extra_pins=pins)
 
-        self._append = jax.jit(jax.vmap(_append))
-        self._reset = jax.jit(jax.vmap(_reset))
-        self._fork = jax.jit(jax.vmap(_fork))
-        self._reclaim_v = jax.jit(jax.vmap(_reclaim))
-        self._evict_v = jax.jit(jax.vmap(_evict))
-        self._gate = jax.jit(jax.vmap(functools.partial(
-            paged.page_pressure, watermark=cfg.page_watermark)))
-        self._hot = jax.jit(jax.vmap(functools.partial(
-            paged.hot_sequences, k=cfg.hot_k)))
+        per_shard = functools.partial(_per_shard, mesh=mesh, axis=axis)
+        self._append = per_shard(_append)
+        self._reset = per_shard(_reset)
+        self._fork = per_shard(_fork)
+        self._reclaim_v = per_shard(_reclaim)
+        self._evict_v = per_shard(_evict)
+        self._gate = per_shard(functools.partial(
+            paged.page_pressure, watermark=cfg.page_watermark))
+        self._hot = per_shard(functools.partial(paged.hot_sequences,
+                                                k=cfg.hot_k))
 
         self.watchdogs: List[StepWatchdog] = [StepWatchdog()
                                               for _ in range(hosts)]
@@ -233,10 +274,13 @@ class ShardedPagedKVEngine:
     def _note_peak(self) -> None:
         self.stats.note_live(int(self.live_pages()))
 
-    def _reclaim_once(self, pins: jax.Array, extra_deficit: int = 0) -> None:
+    def _reclaim_once(self, pins: jax.Array, extra_deficit=0) -> None:
+        """One reclaim pass on every shard.  ``extra_deficit`` (scalar or
+        ``[H]``) is each shard's own count of failed lanes, so a shard
+        chases exactly what the single-host engine would."""
         gate = self._gate(self.st)
-        deficit = jnp.maximum(gate.deficit,
-                              max(1, extra_deficit)).astype(jnp.int32)
+        deficit = jnp.maximum(
+            gate.deficit, np.maximum(1, extra_deficit)).astype(jnp.int32)
         self.st, pages = self._reclaim_v(self.st, self._hot(self.st),
                                          deficit, pins)
         freed = int(pages.sum())
@@ -266,7 +310,7 @@ class ShardedPagedKVEngine:
         rounds = 0
         while bool(failed.any()) and rounds < self.gc.max_reclaim_rounds:
             self.stats.note_event()
-            self._reclaim_once(pins, extra_deficit=int(failed.sum()))
+            self._reclaim_once(pins, extra_deficit=_per_host(failed))
             pins = self.lwm_pins()
             self.st, failed = self._append(self.st, seq_ids, k_new, v_new,
                                            failed, pins)
@@ -286,7 +330,7 @@ class ShardedPagedKVEngine:
         rounds = 0
         while bool(failed.any()) and rounds < self.gc.max_reclaim_rounds:
             self.stats.note_event()
-            self._reclaim_once(pins, extra_deficit=int(failed.sum()))
+            self._reclaim_once(pins, extra_deficit=_per_host(failed))
             pins = self.lwm_pins()
             self.st, failed = self._reset(self.st, seq_ids, failed, pins)
             rounds += 1
@@ -303,7 +347,7 @@ class ShardedPagedKVEngine:
         rounds = 0
         while bool(failed.any()) and rounds < self.gc.max_reclaim_rounds:
             self.stats.note_event()
-            self._reclaim_once(pins, extra_deficit=int(failed.sum()))
+            self._reclaim_once(pins, extra_deficit=_per_host(failed))
             pins = self.lwm_pins()
             self.st, failed = self._fork(self.st, src_ids, dst_ids,
                                          failed, pins)
@@ -348,15 +392,18 @@ class ShardedPagedKVEngine:
         self.st = self.st._replace(mv=self.st.mv._replace(board=board))
 
     def host_state(self, host: int) -> paged.PagedKV:
-        """This host's shard as a plain single-host ``PagedKV`` view."""
-        return jax.tree.map(lambda x: x[host], self.st)
+        """This host's shard as a plain single-host ``PagedKV`` view, on
+        the device that holds it."""
+        return jax.tree.map(lambda x: _host_slice(x, host), self.st)
 
     def view_at(self, host: int, t: int,
                 seq_ids: Optional[jax.Array] = None
                 ) -> Tuple[jax.Array, jax.Array]:
         """Snapshot-read ``host``'s shard at pinned time ``t`` (page tables
         + visible lengths), exactly ``paged.snapshot_view`` on the slice."""
-        local = self.host_state(host)
+        # the read needs the page tables and descriptors, not the pool
+        local = jax.tree.map(lambda x: _host_slice(x, host),
+                             self.st._replace(k_pages=None, v_pages=None))
         if seq_ids is None:
             seq_ids = jnp.arange(local.mv.store.ts.shape[0], dtype=jnp.int32)
         return paged.snapshot_view(local, seq_ids, jnp.int32(t),

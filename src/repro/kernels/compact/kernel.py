@@ -2,7 +2,7 @@
 
 Hardware mapping (DESIGN.md §6): the paper's merge pass over (version list ×
 sorted announcements) becomes a VPU broadcast-compare — the announcement
-vector (P is at most a few thousand: KBs) stays resident in VMEM while the
+vector (P is at most a few thousand: KBs) stays resident in SMEM while the
 [S, V] slab streams through in (BLOCK_S, V) tiles.  Arithmetic intensity is
 O(P) per element, so for realistic P (>= 64) the sweep is compute-bound on
 the VPU rather than HBM-bound — which is why fusing the mask computation into
@@ -23,15 +23,22 @@ TS_MAX = 2_147_483_647
 DEFAULT_BLOCK_S = 256
 
 
-def _compact_kernel(now_ref, ts_ref, succ_ref, ann_ref, out_ref):
-    ts = ts_ref[...]            # (BS, V)
-    succ = succ_ref[...]        # (BS, V)
-    A = ann_ref[...]            # (P,)
-    now = now_ref[0]
-    pinned = (
-        (ts[..., None] <= A[None, None, :]) & (A[None, None, :] < succ[..., None])
-    ).any(-1)
-    out_ref[...] = ((ts != EMPTY) & (pinned | (succ > now))).astype(jnp.int8)
+def _needed_tile(ts, succ, ann_ref, now):
+    """needed(A, now) over one (rows, V) tile.  The announcement board sits
+    in SMEM and is read one scalar per iteration, each compared against the
+    whole tile: the sweep stays 2-D on the VPU for any board size P."""
+    def pin(p, pinned):
+        a = ann_ref[p]
+        return pinned | jnp.where((ts <= a) & (a < succ), 1, 0)
+
+    pinned = jax.lax.fori_loop(0, ann_ref.shape[0], pin,
+                               jnp.zeros(ts.shape, jnp.int32))
+    return (ts != EMPTY) & ((pinned != 0) | (succ > now))
+
+
+def _compact_kernel(now_ref, ann_ref, ts_ref, succ_ref, out_ref):
+    need = _needed_tile(ts_ref[...], succ_ref[...], ann_ref, now_ref[0])
+    out_ref[...] = need.astype(jnp.int8)
 
 
 def needed_pallas(
@@ -45,24 +52,18 @@ def needed_pallas(
 ) -> jax.Array:
     """needed(A, now) as int8[S, V] (1 = needed)."""
     S, V = ts.shape
-    P = ann_sorted.shape[0]
     bs = min(block_s, S)
-    grid = (pl.cdiv(S, bs),)
     now_arr = jnp.reshape(jnp.asarray(now, jnp.int32), (1,))
-    out = pl.pallas_call(
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    tile = pl.BlockSpec((bs, V), lambda i: (i, 0))
+    return pl.pallas_call(
         _compact_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),            # now (scalar)
-            pl.BlockSpec((bs, V), lambda i: (i, 0)),           # ts tile
-            pl.BlockSpec((bs, V), lambda i: (i, 0)),           # succ tile
-            pl.BlockSpec((P,), lambda i: (0,)),                # announcements (resident)
-        ],
-        out_specs=pl.BlockSpec((bs, V), lambda i: (i, 0)),
+        grid=(pl.cdiv(S, bs),),
+        in_specs=[smem, smem, tile, tile],       # now, board; ts, succ tiles
+        out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((S, V), jnp.int8),
         interpret=interpret,
-    )(now_arr, ts, succ, ann_sorted)
-    return out
+    )(now_arr, ann_sorted, ts, succ)
 
 
 def _fused_compact_kernel(
@@ -74,22 +75,19 @@ def _fused_compact_kernel(
     ts = ts_ref[...]            # (BR, V)
     succ = succ_ref[...]        # (BR, V)
     pay = pay_ref[...]          # (BR, V)
-    m = mask_ref[...]           # (BR,) i32: 1 = row eligible
-    A = ann_ref[...]            # (P,)
-    now = now_ref[0]
-    pinned = (
-        (ts[..., None] <= A[None, None, :]) & (A[None, None, :] < succ[..., None])
-    ).any(-1)
-    need = (ts != EMPTY) & (pinned | (succ > now))
-    kill = (ts != EMPTY) & ~need & (m[:, None] != 0)
+    m = mask_ref[...]           # (BR, 1) i32: 1 = row eligible
+    need = _needed_tile(ts, succ, ann_ref, now_ref[0])
+    kill = (ts != EMPTY) & ~need & (m != 0)
     out_ts_ref[...] = jnp.where(kill, EMPTY, ts)
     out_succ_ref[...] = jnp.where(kill, TS_MAX, succ)
     out_pay_ref[...] = jnp.where(kill, EMPTY, pay)
     out_freed_ref[...] = jnp.where(kill, pay, EMPTY)
-    # per-block freed count; padding rows in the last tile must not count
+    # per-block freed count, broadcast over a lane-dense (8, 128) tile;
+    # padding rows in the last tile must not count
     br = ts.shape[0]
     rid = jax.lax.broadcasted_iota(jnp.int32, (br, 1), 0) + pl.program_id(0) * br
-    out_cnt_ref[0] = (kill & (rid < num_rows)).sum().astype(jnp.int32)
+    cnt = jnp.sum(jnp.where(kill & (rid < num_rows), 1, 0))
+    out_cnt_ref[...] = jnp.full(out_cnt_ref.shape, cnt, jnp.int32)
 
 
 def compact_pallas(
@@ -116,41 +114,29 @@ def compact_pallas(
     br = min(block_r, R)
     steps = pl.cdiv(R, br)
     now_arr = jnp.reshape(jnp.asarray(now, jnp.int32), (1,))
-    mask_i32 = mask.astype(jnp.int32)
+    mask_col = mask.astype(jnp.int32)[:, None]
 
     def tile(i, now_ref, ann_ref):
         return (i, 0)
 
-    def lane(i, now_ref, ann_ref):
-        return (i,)
+    def count(i, now_ref, ann_ref):
+        return (i, 0, 0)
 
+    t_spec = pl.BlockSpec((br, V), tile)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(steps,),
-        in_specs=[
-            pl.BlockSpec((br, V), tile),    # ts
-            pl.BlockSpec((br, V), tile),    # succ
-            pl.BlockSpec((br, V), tile),    # payload
-            pl.BlockSpec((br,), lane),      # row mask
-        ],
-        out_specs=(
-            pl.BlockSpec((br, V), tile),    # ts'
-            pl.BlockSpec((br, V), tile),    # succ'
-            pl.BlockSpec((br, V), tile),    # payload'
-            pl.BlockSpec((br, V), tile),    # freed handles
-            pl.BlockSpec((1,), lane),       # per-block freed count
-        ),
+        in_specs=[t_spec, t_spec, t_spec,            # ts, succ, payload
+                  pl.BlockSpec((br, 1), tile)],      # row mask
+        out_specs=(t_spec, t_spec, t_spec, t_spec,   # ts', succ', pay', freed
+                   pl.BlockSpec((1, 8, 128), count)),  # per-block count
     )
+    tile_out = jax.ShapeDtypeStruct((R, V), jnp.int32)
     new_ts, new_succ, new_pay, freed, cnt = pl.pallas_call(
         functools.partial(_fused_compact_kernel, R),
         grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((R, V), jnp.int32),
-            jax.ShapeDtypeStruct((R, V), jnp.int32),
-            jax.ShapeDtypeStruct((R, V), jnp.int32),
-            jax.ShapeDtypeStruct((R, V), jnp.int32),
-            jax.ShapeDtypeStruct((steps,), jnp.int32),
-        ),
+        out_shape=(tile_out, tile_out, tile_out, tile_out,
+                   jax.ShapeDtypeStruct((steps, 8, 128), jnp.int32)),
         interpret=interpret,
-    )(now_arr, ann_sorted, ts, succ, payload, mask_i32)
-    return new_ts, new_succ, new_pay, freed, cnt.sum()
+    )(now_arr, ann_sorted, ts, succ, payload, mask_col)
+    return new_ts, new_succ, new_pay, freed, cnt[:, 0, 0].sum()

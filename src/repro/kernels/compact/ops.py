@@ -2,10 +2,12 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.core.telemetry import resolve_kernel
 from repro.kernels.compact.kernel import compact_pallas, needed_pallas
 from repro.kernels.compact.ref import compact_ref, needed_ref
 
@@ -17,11 +19,12 @@ def needed(
     ann_sorted: jax.Array,
     now: jax.Array,
     *,
-    use_kernel: bool = True,
-    interpret: bool = True,   # CPU container: interpret by default; False on TPU
+    use_kernel: Optional[bool] = None,   # None: by platform (resolve_kernel)
+    interpret: Optional[bool] = None,
     block_s: int = 256,
 ) -> jax.Array:
     """bool[S, V] needed mask; Pallas kernel on TPU, jnp reference otherwise."""
+    use_kernel, interpret = resolve_kernel(use_kernel, interpret)
     if use_kernel:
         return needed_pallas(
             ts, succ, ann_sorted, now, block_s=block_s, interpret=interpret
@@ -38,8 +41,8 @@ def compact(
     ann_sorted: jax.Array,
     now: jax.Array,
     *,
-    use_kernel: bool = True,
-    interpret: bool = True,   # CPU container: interpret by default; False on TPU
+    use_kernel: Optional[bool] = None,   # None: by platform (resolve_kernel)
+    interpret: Optional[bool] = None,
     block_r: int = 256,
 ):
     """Fused needed + splice over an [R, V] row batch.
@@ -47,6 +50,7 @@ def compact(
     Returns ``(ts', succ', payload', freed, n_freed)`` — see ``compact_ref``
     for the contract.  Pallas kernel when ``use_kernel``, jnp reference
     otherwise (the two are parity-tested in tests/kernels)."""
+    use_kernel, interpret = resolve_kernel(use_kernel, interpret)
     if use_kernel:
         return compact_pallas(
             ts, succ, payload, mask, ann_sorted, now,

@@ -2,9 +2,11 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 
+from repro.core.telemetry import resolve_kernel
 from repro.kernels.decode_attention.kernel import paged_decode_pallas
 from repro.kernels.decode_attention.ref import paged_decode_ref
 
@@ -13,8 +15,9 @@ from repro.kernels.decode_attention.ref import paged_decode_ref
 def paged_decode(
     q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     page_table: jax.Array, lengths: jax.Array, *,
-    use_kernel: bool = True, interpret: bool = True,
+    use_kernel: bool = True, interpret: Optional[bool] = None,
 ) -> jax.Array:
+    use_kernel, interpret = resolve_kernel(use_kernel, interpret)
     if use_kernel:
         return paged_decode_pallas(
             q, k_pages, v_pages, page_table, lengths, interpret=interpret)

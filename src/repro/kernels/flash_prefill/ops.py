@@ -2,9 +2,11 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 
+from repro.core.telemetry import resolve_kernel
 from repro.kernels.flash_prefill.kernel import flash_attention_pallas
 from repro.kernels.flash_prefill.ref import attention_ref
 
@@ -16,9 +18,10 @@ STATIC = ("causal", "window", "softcap", "use_kernel", "interpret",
 def flash_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *,
     causal: bool = True, window: int = 0, softcap: float = 0.0,
-    use_kernel: bool = True, interpret: bool = True,
+    use_kernel: bool = True, interpret: Optional[bool] = None,
     block_t: int = 128, block_s: int = 128,
 ) -> jax.Array:
+    use_kernel, interpret = resolve_kernel(use_kernel, interpret)
     if use_kernel:
         return flash_attention_pallas(
             q, k, v, causal=causal, window=window, softcap=softcap,

@@ -1,40 +1,44 @@
 """Pallas TPU kernel: batched version search (the paper's ``search(t)``).
 
-The list traversal becomes a slab-row gather + masked argmax.  Slot indirection
-uses **scalar prefetch** (PrefetchScalarGridSpec): the query's slot id is known
-before the grid step runs, so the BlockSpec index_map steers the DMA to the
-right slab row — the same mechanism TPU paged-attention kernels use for page
-tables.  One grid step handles a (BLOCK_B, V) tile of queries; V is the slab
-width (small, e.g. 8-32), so the reduction is a cheap VPU max-scan across
-lanes.
+The list traversal becomes a slab-row gather + masked max.  The queried rows
+are gathered by XLA before the launch (``ts[slot_ids]``); one grid step then
+resolves a (BLOCK_B, V) tile of queries.  V is the slab width (small, e.g.
+8-32), so the reduction is a cheap VPU max-scan across lanes.
+
+Per-query vectors travel as ``[B, 1]`` columns: a rank-1 block would have to
+be a multiple of 128 (512 for int8) or the whole array, and a column keeps
+the per-query values on sublanes, where they broadcast against the tile.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 EMPTY = -1                      # plain ints: no captured tracers in kernels
 NEG_INF_I32 = -2_147_483_648
 DEFAULT_BLOCK_B = 128
 
 
-def _search_kernel(ids_ref, t_ref, ts_ref, pay_ref, out_pay_ref, out_found_ref):
-    b = pl.program_id(0)
-    bs = t_ref.shape[0]
-    # rows were DMA'd for this query block via the index_map below
-    rows_ts = ts_ref[...]          # (BS, V)
-    rows_pay = pay_ref[...]        # (BS, V)
-    t = t_ref[...]                 # (BS,)
-    ok = (rows_ts != EMPTY) & (rows_ts <= t[:, None])
+def _resolve(t, rows_ts, rows_pay):
+    """search(t) over one tile: ``(payload[BB, 1], found[BB, 1])``, taking
+    the first column of the newest version at or before ``t`` (argmax's
+    tie rule, so the kernel matches ``ref.search_ref`` bit for bit)."""
+    V = rows_ts.shape[1]
+    ok = (rows_ts != EMPTY) & (rows_ts <= t)
     masked = jnp.where(ok, rows_ts, NEG_INF_I32)
-    idx = jnp.argmax(masked, axis=1)
-    found = ok.any(axis=1)
-    onehot = jax.nn.one_hot(idx, rows_ts.shape[1], dtype=jnp.int32)
-    pay = (rows_pay * onehot).sum(axis=1)
-    out_pay_ref[...] = jnp.where(found, pay, EMPTY)
-    out_found_ref[...] = found.astype(jnp.int8)
+    best = masked.max(axis=1, keepdims=True)
+    col = jax.lax.broadcasted_iota(jnp.int32, rows_ts.shape, 1)
+    first = jnp.where(masked == best, col, V).min(axis=1, keepdims=True)
+    found = jnp.where(ok, 1, 0).max(axis=1, keepdims=True) > 0
+    pay = jnp.where(col == first, rows_pay, 0).sum(axis=1, keepdims=True)
+    return jnp.where(found, pay, EMPTY), found
+
+
+def _search_kernel(t_ref, ts_ref, pay_ref, out_pay_ref, out_found_ref):
+    pay, found = _resolve(t_ref[...], ts_ref[...], pay_ref[...])
+    out_pay_ref[...] = pay
+    out_found_ref[...] = found.astype(jnp.int32)
 
 
 def search_pallas(
@@ -49,67 +53,43 @@ def search_pallas(
     S, V = ts.shape
     B = slot_ids.shape[0]
     bb = min(block_b, B)
-    grid = (pl.cdiv(B, bb),)
-
-    # Gather the queried rows on the host side of the kernel via scalar-
-    # prefetched indices: each grid step b sees rows slot_ids[b*bb:(b+1)*bb].
-    # We pre-gather with a cheap XLA gather (rows are contiguous per query),
-    # then the kernel streams (bb, V) tiles; for very large V the gather
-    # itself would move into the kernel with make_async_copy.
     rows_ts = ts[slot_ids]          # [B, V]
     rows_pay = payload[slot_ids]    # [B, V]
-
-    out_shape = (
-        jax.ShapeDtypeStruct((B,), jnp.int32),
-        jax.ShapeDtypeStruct((B,), jnp.int8),
-    )
+    col = pl.BlockSpec((bb, 1), lambda i: (i, 0))
+    tile = pl.BlockSpec((bb, V), lambda i: (i, 0))
     pay, found = pl.pallas_call(
         _search_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bb,), lambda i: (i,)),       # slot ids (unused in body)
-            pl.BlockSpec((bb,), lambda i: (i,)),       # timestamps
-            pl.BlockSpec((bb, V), lambda i: (i, 0)),   # gathered ts rows
-            pl.BlockSpec((bb, V), lambda i: (i, 0)),   # gathered payload rows
-        ],
-        out_specs=(
-            pl.BlockSpec((bb,), lambda i: (i,)),
-            pl.BlockSpec((bb,), lambda i: (i,)),
-        ),
-        out_shape=out_shape,
+        grid=(pl.cdiv(B, bb),),
+        in_specs=[col, tile, tile],
+        out_specs=(col, col),
+        out_shape=(jax.ShapeDtypeStruct((B, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((B, 1), jnp.int32)),
         interpret=interpret,
-    )(slot_ids, t, rows_ts, rows_pay)
-    return pay, found.astype(jnp.bool_)
+    )(t[:, None], rows_ts, rows_pay)
+    return pay[:, 0], found[:, 0] != 0
 
 
 def _search_gather_kernel(
     t_ref, ts_ref, pay_ref, val_ref,
     out_rows_ref, out_pay_ref, out_found_ref,
 ):
-    rows_ts = ts_ref[...]          # (BB, V)
-    rows_pay = pay_ref[...]        # (BB, V)
-    t = t_ref[...]                 # (BB,)
-    ok = (rows_ts != EMPTY) & (rows_ts <= t[:, None])
-    masked = jnp.where(ok, rows_ts, NEG_INF_I32)
-    idx = jnp.argmax(masked, axis=1)
-    found = ok.any(axis=1)
-    onehot = jax.nn.one_hot(idx, rows_ts.shape[1], dtype=jnp.int32)
-    pay = jnp.where(found, (rows_pay * onehot).sum(axis=1), EMPTY)
+    pay, found = _resolve(t_ref[...], ts_ref[...], pay_ref[...])
     out_pay_ref[...] = pay
-    out_found_ref[...] = found.astype(jnp.int8)
-    # gather the resolved value rows: per-query dynamic-slice DMA against the
-    # VMEM-resident values block (the paged-attention page-walk idiom)
+    out_found_ref[...] = found.astype(jnp.int32)
+    # gather the resolved value rows from the VMEM-resident values block:
+    # per query, reduce its row index to a scalar and copy one row by ref
+    # indexing (EMPTY-filled when the query found nothing)
     T = val_ref.shape[0]
-    safe = jnp.clip(pay, 0, T - 1)
-    bb = rows_ts.shape[0]
+    key = jnp.where(found, jnp.clip(pay, 0, T - 1), EMPTY)     # (BB, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, key.shape, 0)
 
-    def body(i, _):
-        row = pl.load(val_ref, (pl.ds(safe[i], 1), slice(None)))   # (1, M)
-        row = jnp.where(found[i], row, EMPTY)
-        pl.store(out_rows_ref, (pl.ds(i, 1), slice(None)), row)
-        return 0
+    def body(i, carry):
+        k = jnp.sum(jnp.where(lane == i, key, 0))
+        row = val_ref[pl.ds(jnp.maximum(k, 0), 1), :]           # (1, M)
+        out_rows_ref[pl.ds(i, 1), :] = jnp.where(k >= 0, row, EMPTY)
+        return carry
 
-    jax.lax.fori_loop(0, bb, body, 0)
+    jax.lax.fori_loop(0, key.shape[0], body, 0)
 
 
 def search_gather_pallas(
@@ -122,36 +102,30 @@ def search_gather_pallas(
     block_b: int = DEFAULT_BLOCK_B,
     interpret: bool = False,
 ):
-    """One launch: batched search(t) + gather of the resolved value rows."""
+    """One launch: batched search(t) + gather of the resolved value rows.
+
+    ``values`` is held whole in VMEM (padded to 128 lanes, double-buffered),
+    so ``T * 128 * 4 * 2`` bytes must fit the kernel's VMEM budget: 8 MiB
+    for the 8192 page-table versions of a 1024-sequence paged cache."""
     S, V = ts.shape
     T, M = values.shape
     B = slot_ids.shape[0]
     bb = min(block_b, B)
-    grid = (pl.cdiv(B, bb),)
-
     rows_ts = ts[slot_ids]          # [B, V] (pre-gathered; see search_pallas)
     rows_pay = payload[slot_ids]    # [B, V]
-
-    out_shape = (
-        jax.ShapeDtypeStruct((B, M), jnp.int32),
-        jax.ShapeDtypeStruct((B,), jnp.int32),
-        jax.ShapeDtypeStruct((B,), jnp.int8),
-    )
+    col = pl.BlockSpec((bb, 1), lambda i: (i, 0))
+    tile = pl.BlockSpec((bb, V), lambda i: (i, 0))
     rows, pay, found = pl.pallas_call(
         _search_gather_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bb,), lambda i: (i,)),       # timestamps
-            pl.BlockSpec((bb, V), lambda i: (i, 0)),   # gathered ts rows
-            pl.BlockSpec((bb, V), lambda i: (i, 0)),   # gathered payload rows
-            pl.BlockSpec((T, M), lambda i: (0, 0)),    # values (resident)
-        ],
-        out_specs=(
-            pl.BlockSpec((bb, M), lambda i: (i, 0)),
-            pl.BlockSpec((bb,), lambda i: (i,)),
-            pl.BlockSpec((bb,), lambda i: (i,)),
+        grid=(pl.cdiv(B, bb),),
+        in_specs=[col, tile, tile,
+                  pl.BlockSpec((T, M), lambda i: (0, 0))],  # values (resident)
+        out_specs=(pl.BlockSpec((bb, M), lambda i: (i, 0)), col, col),
+        out_shape=(
+            jax.ShapeDtypeStruct((B, M), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1), jnp.int32),
         ),
-        out_shape=out_shape,
         interpret=interpret,
-    )(t, rows_ts, rows_pay, values)
-    return rows, pay, found.astype(jnp.bool_)
+    )(t[:, None], rows_ts, rows_pay, values)
+    return rows, pay[:, 0], found[:, 0] != 0

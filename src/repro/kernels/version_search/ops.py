@@ -2,11 +2,12 @@
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.core.telemetry import resolve_kernel
 from repro.kernels.version_search.kernel import search_gather_pallas, search_pallas
 from repro.kernels.version_search.ref import search_gather_ref, search_ref
 
@@ -18,10 +19,11 @@ def search(
     slot_ids: jax.Array,
     t: jax.Array,
     *,
-    use_kernel: bool = True,
-    interpret: bool = True,
+    use_kernel: Optional[bool] = None,   # None: by platform (resolve_kernel)
+    interpret: Optional[bool] = None,
     block_b: int = 128,
 ) -> Tuple[jax.Array, jax.Array]:
+    use_kernel, interpret = resolve_kernel(use_kernel, interpret)
     if use_kernel:
         return search_pallas(
             ts, payload, slot_ids, t, block_b=block_b, interpret=interpret
@@ -37,13 +39,14 @@ def search_gather(
     slot_ids: jax.Array,
     t: jax.Array,
     *,
-    use_kernel: bool = True,
-    interpret: bool = True,
+    use_kernel: Optional[bool] = None,   # None: by platform (resolve_kernel)
+    interpret: Optional[bool] = None,
     block_b: int = 128,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Fused batched search(t) + value-row gather: one launch resolves a
     batch of (slot, ts) snapshot reads AND gathers the payload-indexed rows.
     Returns ``(rows[B, M], payload[B], found[B])``."""
+    use_kernel, interpret = resolve_kernel(use_kernel, interpret)
     if use_kernel:
         return search_gather_pallas(
             ts, payload, values, slot_ids, t, block_b=block_b, interpret=interpret

@@ -34,9 +34,18 @@ def make_gc_mesh(hosts: int = 0, axis: str = "gc_hosts"):
     """1-D mesh for the sharded MVGC stack (``repro.dist.mvgc``): one
     position per host along ``axis``.  ``hosts=0`` uses every available
     device.  The global-LWM ring all-reduce and the per-shard GC shard_maps
-    both run over this axis (DESIGN.md §13)."""
+    both run over this axis (DESIGN.md §13).
+
+    More hosts than devices is a mesh of every device, each holding
+    ``hosts / devices`` shards, so the device count must divide ``hosts``;
+    anything else raises rather than running on fewer devices.  A single
+    device gives the one-position mesh on which the stack stays unsharded."""
     n = len(jax.devices())
-    hosts = n if hosts <= 0 else min(hosts, n)
+    size = n if hosts <= 0 else min(hosts, n)
+    if hosts > n > 1 and hosts % n:
+        raise ValueError(
+            f"{hosts} MVGC hosts cannot be laid out over {n} devices: the "
+            f"device count must divide the host count")
     return jax.make_mesh(
-        (hosts,), (axis,), axis_types=(jax.sharding.AxisType.Auto,),
+        (size,), (axis,), axis_types=(jax.sharding.AxisType.Auto,),
     )

@@ -22,6 +22,7 @@ from repro.configs import get_config, reduced_config
 from repro.configs.base import RunConfig, SHAPES
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.dist.straggler import HeartbeatFile, StepWatchdog
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train.step import TrainState, init_state, train_step
 
 
@@ -43,6 +44,7 @@ def main() -> None:
                     help="abort at this step (fault-tolerance demo)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     run = RunConfig(model=cfg, shape=SHAPES["train_4k"], lr=args.lr,
                     microbatches=args.microbatches,

@@ -197,7 +197,7 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int, dtype=jnp.bfloat16)
 
 
 def _serve_pass(params, cfg: ModelConfig, tokens, cache, cache_len, mode,
-                enc_out=None, frontend_embeds=None):
+                enc_out=None, frontend_embeds=None, last_only=False):
     B, T = tokens.shape
     x = params["embed"][tokens]
     if cfg.embed_scale:
@@ -229,6 +229,10 @@ def _serve_pass(params, cfg: ModelConfig, tokens, cache, cache_len, mode,
                               enc_out=enc_out, mode=mode)
         new_tail.append(c)
 
+    if last_only:
+        # prefill reads one position; a [B, T, V] logits block of a 256k
+        # vocabulary would not fit beside the model
+        x = x[:, -1:]
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     unembed = params.get("unembed", params["embed"])
     logits = jnp.einsum("btd,vd->btv", x, unembed)
@@ -269,6 +273,7 @@ def prefill(
         fe = None
     zeros = jnp.zeros((B,), jnp.int32)
     logits, cache = _serve_pass(params, cfg, tokens, cache, zeros, "prefill",
-                                enc_out=enc_out, frontend_embeds=fe)
+                                enc_out=enc_out, frontend_embeds=fe,
+                                last_only=True)
     total = tokens.shape[1] + (fe.shape[1] if fe is not None else 0)
-    return logits[:, -1:], cache, zeros + total
+    return logits, cache, zeros + total

@@ -99,8 +99,8 @@ def append_tokens(
     v_new: jax.Array,      # [B, Hkv, D]
     mask: jax.Array,       # bool[B]
     gc_policy: str = "slrt",
-    use_kernel: bool = False,
-    interpret: bool = True,
+    use_kernel: Optional[bool] = None,
+    interpret: Optional[bool] = None,
     extra_pins: Optional[jax.Array] = None,
 ) -> Tuple[PagedKV, jax.Array]:
     """One decode step: write each sequence's token into its current page,
@@ -191,8 +191,8 @@ def reset_sequence(
     seq_ids: jax.Array,    # i32[B] sequence slots being recycled
     mask: jax.Array,       # bool[B]
     gc_policy: str = "slrt",
-    use_kernel: bool = False,
-    interpret: bool = True,
+    use_kernel: Optional[bool] = None,
+    interpret: Optional[bool] = None,
     extra_pins: Optional[jax.Array] = None,
 ) -> Tuple[PagedKV, jax.Array]:
     """Sequence completion: commit a new *empty* page-table version (zero
@@ -230,8 +230,8 @@ def fork_sequence(
     dst_ids: jax.Array,    # i32[B] child sequence slots
     mask: jax.Array,       # bool[B]
     gc_policy: str = "slrt",
-    use_kernel: bool = False,
-    interpret: bool = True,
+    use_kernel: Optional[bool] = None,
+    interpret: Optional[bool] = None,
     extra_pins: Optional[jax.Array] = None,
     copy_pages: bool = False,
 ) -> Tuple[PagedKV, jax.Array]:
@@ -361,8 +361,8 @@ def reclaim_on_pressure(
     hot_keys: jax.Array,   # i32[K] hot sequence ids (-1 = inert lane)
     deficit: jax.Array,    # i32[] pages wanted (page_pressure().deficit)
     gc_policy: str = "slrt",
-    use_kernel: bool = False,
-    interpret: bool = True,
+    use_kernel: Optional[bool] = None,
+    interpret: Optional[bool] = None,
     extra_pins: Optional[jax.Array] = None,
     ckpt_max: Optional[jax.Array] = None,
 ) -> Tuple[PagedKV, jax.Array]:
@@ -437,7 +437,8 @@ def _sweep_unreferenced(tables, table_free, page_free) -> jax.Array:
 
 
 def snapshot_view(st: PagedKV, seq_ids: jax.Array, t: jax.Array,
-                  use_kernel: bool = False, interpret: bool = True,
+                  use_kernel: Optional[bool] = None,
+                  interpret: Optional[bool] = None,
                   ) -> Tuple[jax.Array, jax.Array]:
     """Resolve a pinned timestamp to (page_table[B, MP], lengths[B]) — the
     rtx read: feed straight into kernels.decode_attention.paged_decode.

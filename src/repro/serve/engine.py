@@ -82,7 +82,7 @@ def prefill_step(state: ServeState, cfg: ModelConfig, run: RunConfig,
     ids = jnp.arange(B, dtype=jnp.int32)
     mv, _, _ = vstore.write_step(
         state.mv, ids, lens, jnp.ones((B,), bool), policy=run.gc.policy,
-        use_kernel=run.gc.use_kernel, interpret=run.gc.kernel_interpret)
+        **run.gc.kernel_kwargs())
     nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
     return ServeState(state.params, cache, lens, mv, nxt)
 
@@ -109,7 +109,7 @@ def decode_one(state: ServeState, cfg: ModelConfig, run: RunConfig,
     # the update: a new descriptor version (visible length) per sequence
     mv, freed_w, ovf = vstore.write_step(
         state.mv, ids, new_len, jnp.ones((B,), bool), policy=run.gc.policy,
-        use_kernel=run.gc.use_kernel, interpret=run.gc.kernel_interpret)
+        **run.gc.kernel_kwargs())
     gate = vstore.capacity_gate(mv)
     trigger = gate.under_pressure | ovf.any()
 
@@ -117,13 +117,12 @@ def decode_one(state: ServeState, cfg: ModelConfig, run: RunConfig,
         hs = vstore.hot_slots(m, min(8, B))
         m2, _, n = vstore.reclaim_on_pressure(
             m, hs, gate.deficit, policy=run.gc.policy,
-            use_kernel=run.gc.use_kernel, interpret=run.gc.kernel_interpret)
+            **run.gc.kernel_kwargs())
         return m2, jnp.int32(1), n
 
     def _cadence(m: vstore.MVState):
         m2, freed_g = vstore.gc_step(m, policy=run.gc.policy,
-                                     use_kernel=run.gc.use_kernel,
-                                     interpret=run.gc.kernel_interpret)
+                                     **run.gc.kernel_kwargs())
         return m2, jnp.int32(0), (freed_g != EMPTY).sum().astype(jnp.int32)
 
     mv, reclaimed, n_freed = jax.lax.cond(trigger, _pressure, _cadence, mv)
@@ -133,7 +132,7 @@ def decode_one(state: ServeState, cfg: ModelConfig, run: RunConfig,
         m, o = args
         m2, _, o2 = vstore.write_step(
             m, ids, new_len, o, policy=run.gc.policy,
-            use_kernel=run.gc.use_kernel, interpret=run.gc.kernel_interpret)
+            **run.gc.kernel_kwargs())
         return m2, o2
 
     mv, ovf_left = jax.lax.cond(
@@ -171,7 +170,8 @@ def end_snapshot(state: ServeState, lane: jax.Array) -> ServeState:
 
 def snapshot_lengths(state: ServeState, t: jax.Array,
                      seq_ids: Optional[jax.Array] = None,
-                     use_kernel: bool = False, interpret: bool = True,
+                     use_kernel: Optional[bool] = None,
+                     interpret: Optional[bool] = None,
                      ) -> Tuple[jax.Array, jax.Array]:
     """Consistent cross-sequence snapshot: each sequence's visible length as
     of pinned time t (the paper's rtx over many vCAS objects)."""
@@ -202,19 +202,35 @@ class MVServeEngine:
                  max_len: int, dtype=jnp.float32):
         self.cfg, self.run = cfg, run
         self.state = make_serve_state(cfg, run, params, batch, max_len, dtype)
-        self._decode = jax.jit(
-            functools.partial(decode_one, cfg=cfg, run=run))
-        self._prefill = jax.jit(
-            functools.partial(prefill_step, cfg=cfg, run=run))
+        # the jitted steps return the state without its parameters: they
+        # pass through unchanged, and a jitted program would write a fresh
+        # copy of every weight on each call
+        def decode(state):
+            new, toks, freed, stats = decode_one(state, cfg, run)
+            return new._replace(params=None), toks, freed, stats
+
+        def prefill(state, tokens):
+            return prefill_step(state, cfg, run, tokens)._replace(params=None)
+
+        self._decode = jax.jit(decode)
+        self._prefill = jax.jit(prefill)
         self.last_stats: Dict[str, int] = {}
 
     def prefill(self, tokens: jax.Array) -> None:
-        self.state = self._prefill(self.state, tokens=tokens)
+        new = self._prefill(self.state, tokens)
+        self.state = new._replace(params=self.state.params)
 
     def step(self) -> jax.Array:
-        self.state, toks, _, stats = self._decode(self.state)
+        new, toks, _, stats = self._decode(self.state)
+        self.state = new._replace(params=self.state.params)
         self.last_stats = {k: int(v) for k, v in stats.items()}
         return toks
+
+    def compile_decode(self) -> jax.stages.Compiled:
+        """Compile the decode step for the current state ahead of the first
+        `step`, which then reuses the program; its ``as_text()`` shows which
+        kernels the step runs."""
+        return self._decode.lower(self.state).compile()
 
     def pin(self, lane: int) -> int:
         self.state, ts = begin_snapshot(self.state, jnp.int32(lane))
@@ -224,7 +240,8 @@ class MVServeEngine:
         self.state = end_snapshot(self.state, jnp.int32(lane))
 
     def lengths_at(self, t: int) -> jax.Array:
-        lens, found = snapshot_lengths(self.state, jnp.int32(t))
+        lens, found = snapshot_lengths(self.state, jnp.int32(t),
+                                       **self.run.gc.kernel_kwargs())
         return jnp.where(found, lens, 0)
 
     def space(self) -> Dict[str, int]:
@@ -279,9 +296,9 @@ class PagedKVEngine:
             head_dim, gc=cfg, dtype=dtype)
         self.gc_policy = cfg.policy
         self.max_reclaim_rounds = cfg.max_reclaim_rounds
-        self.use_kernel = cfg.use_kernel
-        self.kernel_interpret = cfg.kernel_interpret
         kern = cfg.kernel_kwargs()
+        self.use_kernel = kern["use_kernel"]
+        self.kernel_interpret = kern["interpret"]
         self._append = jax.jit(
             functools.partial(paged.append_tokens, gc_policy=cfg.policy,
                               **kern))
@@ -372,8 +389,9 @@ class PagedKVEngine:
         """Append one token per masked sequence; reclaim-and-retry on
         pressure.  Returns failed[B] (True = gave up after reclaims)."""
         free_before = np.asarray(self.st.free)
-        st, failed = self._append(self.st, seq_ids, k_new, v_new, mask)
-        self.st = st
+        # assigned straight to self.st: a local would keep this state (and
+        # its copy of the page pool) alive through the reclaim and retry
+        self.st, failed = self._append(self.st, seq_ids, k_new, v_new, mask)
         self._note_peak()
         rounds = 0
         while bool(failed.any()) and rounds < self.max_reclaim_rounds:
@@ -399,8 +417,7 @@ class PagedKVEngine:
         same reclaim-and-retry discipline as `step` — shared by `fork` and
         `join`, which differ only in lineage bookkeeping."""
         free_before = np.asarray(self.st.free)
-        st, failed = self._fork(self.st, src_ids, dst_ids, mask)
-        self.st = st
+        self.st, failed = self._fork(self.st, src_ids, dst_ids, mask)
         self._note_peak()
         rounds = 0
         while bool(failed.any()) and rounds < self.max_reclaim_rounds:
@@ -468,8 +485,7 @@ class PagedKVEngine:
         """Recycle finished sequences' slots (empty table version); same
         reclaim-and-retry discipline as `step`."""
         free_before = np.asarray(self.st.free)
-        st, failed = self._reset(self.st, seq_ids, mask)
-        self.st = st
+        self.st, failed = self._reset(self.st, seq_ids, mask)
         rounds = 0
         while bool(failed.any()) and rounds < self.max_reclaim_rounds:
             self.stats.note_event()

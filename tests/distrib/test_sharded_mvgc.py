@@ -264,3 +264,49 @@ def test_fresh_hosts_never_aged_with_infinite_budget():
     _churn(eng, 3)
     assert eng.stats.stale_lanes_aged == 0
     assert (eng.budget_s() > 0).all()    # warmup budget is finite, not inf
+
+
+def test_mesh_that_cannot_take_the_stack_raises():
+    """On several devices the stack is sharded or refused, never silently
+    left whole: 4 hosts over 3 devices raise in both the mesh builder and
+    the engine, and 4 hosts over 4 devices put one shard on each."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    code = textwrap.dedent("""
+        import jax, numpy as np, pytest
+        from repro.core.telemetry import GCConfig
+        from repro.dist.mvgc import ShardedPagedKVEngine
+        from repro.launch.mesh import make_gc_mesh
+        assert len(jax.devices()) == 3
+        with pytest.raises(ValueError, match="cannot be laid out"):
+            make_gc_mesh(4)
+        three = jax.make_mesh((3,), ("gc_hosts",))
+        with pytest.raises(ValueError, match="do not divide"):
+            ShardedPagedKVEngine(4, 4, 12, 4, 3, 1, 4, mesh=three,
+                                 gc=GCConfig(reader_lanes=4))
+        eng = ShardedPagedKVEngine(6, 4, 12, 4, 3, 1, 4,
+                                   gc=GCConfig(reader_lanes=4))
+        assert eng._ring is not None
+        for leaf in jax.tree.leaves(eng.st):
+            assert len(leaf.sharding.device_set) == 3
+            assert leaf.addressable_shards[0].data.shape[0] == 2
+        # a host's slice lives on the one device holding its shard, so a
+        # kernel reading it runs there and needs no partitioning
+        for leaf, full in zip(jax.tree.leaves(eng.host_state(3)),
+                              jax.tree.leaves(eng.st)):
+            assert leaf.sharding.device_set == {jax.devices()[1]}
+            np.testing.assert_array_equal(np.asarray(leaf),
+                                          np.asarray(full)[3])
+        print("mesh checks OK")
+    """)
+    env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"),
+               JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=3")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=300, env=env)
+    assert out.returncode == 0, f"{out.stdout}\n{out.stderr}"
+    assert "mesh checks OK" in out.stdout

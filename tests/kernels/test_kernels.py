@@ -59,7 +59,8 @@ class TestCompactKernel:
         rng = np.random.default_rng(0)
         ts, succ, _ = _mk_slabs(rng, 70, 4)  # S not divisible by block
         ann = jnp.array([5, 50, TS_MAX, TS_MAX], jnp.int32)
-        got = compact_needed(ts, succ, ann, jnp.int32(60), block_s=32)
+        got = compact_needed(ts, succ, ann, jnp.int32(60), block_s=32,
+                             use_kernel=True, interpret=True)
         want = needed_ref(ts, succ, ann, jnp.int32(60))
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 

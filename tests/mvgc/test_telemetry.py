@@ -77,6 +77,24 @@ class TestGCConfig:
         gc = GCConfig(use_kernel=True, kernel_interpret=False)
         assert gc.kernel_kwargs() == {"use_kernel": True, "interpret": False}
 
+    def test_kernel_path_by_platform(self, monkeypatch):
+        """Unset, the platform picks the path: the lax path off a TPU, the
+        compiled kernels on one; interpreting on a TPU raises."""
+        import jax
+        assert GCConfig().kernel_kwargs() == {"use_kernel": False,
+                                              "interpret": True}
+        assert GCConfig(use_kernel=True).kernel_kwargs() == {
+            "use_kernel": True, "interpret": True}
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert GCConfig().kernel_kwargs() == {"use_kernel": True,
+                                              "interpret": False}
+        assert GCConfig(use_kernel=False).kernel_kwargs() == {
+            "use_kernel": False, "interpret": False}
+        for gc in (GCConfig(kernel_interpret=True),
+                   GCConfig(use_kernel=True, kernel_interpret=True)):
+            with pytest.raises(ValueError, match="interpret"):
+                gc.kernel_kwargs()
+
     def test_replace(self):
         gc = GCConfig().replace(policy="ebr", hot_k=2)
         assert gc.policy == "ebr" and gc.hot_k == 2
