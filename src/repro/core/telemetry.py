@@ -20,6 +20,11 @@ produces/consumes two types:
   emits the schema-v4 BENCH field names (``pressure_events``,
   ``reclaims_triggered``, ``pages_reclaimed``, ...), so BENCH payloads and
   existing tests stay valid while the engines share one implementation.
+* :class:`EngineCounters`, :func:`span` and :func:`fetch` — what a serving
+  engine's host loop does, on the profiler's clock: ``repro.<layer>.<what>``
+  spans around each layer's calls (nothing is written unless a trace is
+  being taken), and one counted way to read device values on the host,
+  so every blocking device-to-host read is a ``repro.engine.sync`` span.
 
 :class:`GCConfig` collapses the GC/pressure kwarg sprawl that had crept into
 ``make_paged_kv`` / ``PagedKVEngine`` / ``RunConfig`` (policy, slab depth,
@@ -130,6 +135,40 @@ class ReclaimStats:
             "ckpt_evictions": self.ckpt_evictions,
             f"ckpt_{self.unit}_freed": self.ckpt_freed,
         }
+
+
+@dataclasses.dataclass
+class EngineCounters:
+    """Host-side counts of a serving engine's loop: ``steps`` taken, and
+    ``host_syncs``, the blocking device-to-host reads (each one `fetch`)."""
+
+    steps: int = 0
+    host_syncs: int = 0
+
+    def as_row(self) -> Dict[str, int]:
+        return dataclasses.asdict(self)
+
+
+def span(name: str, **args: Any):
+    """A host span ``name`` in the profiler's trace, with ``args`` as its
+    metadata.  It lands on the host plane, on the clock of the device's
+    events, so device idle time can be charged to the span open around it;
+    when no trace is being taken it records nothing."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(name, **args)
+
+
+def fetch(tree: Any, counters: EngineCounters) -> Any:
+    """The one way an engine reads device values on the host: a single
+    ``jax.device_get`` of ``tree`` (one blocking round trip for all its
+    leaves) inside a ``repro.engine.sync`` span, counted in
+    ``counters.host_syncs``.  Returns the tree with numpy leaves."""
+    import jax
+
+    counters.host_syncs += 1
+    with span("repro.engine.sync"):
+        return jax.device_get(tree)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -457,15 +457,28 @@ def snapshot_view(st: PagedKV, seq_ids: jax.Array, t: jax.Array,
     return tables, lengths
 
 
-def begin_snapshot(st: PagedKV, lane: jax.Array) -> Tuple[PagedKV, jax.Array]:
-    mv, ts = vstore.begin_snapshot(st.mv, jnp.atleast_1d(lane),
+def snapshot_pin(mv: vstore.MVState, lane: jax.Array
+                 ) -> Tuple[vstore.MVState, jax.Array]:
+    """Pin reader ``lane`` at the store's clock: (mv', its timestamp).  It
+    takes and returns the descriptor store alone, so that as a program it
+    copies neither the page pool nor a model's weights."""
+    mv, ts = vstore.begin_snapshot(mv, jnp.atleast_1d(lane),
                                    jnp.array([True]))
-    return st._replace(mv=mv), ts[0]
+    return mv, ts[0]
+
+
+def snapshot_unpin(mv: vstore.MVState, lane: jax.Array) -> vstore.MVState:
+    """Release reader ``lane``'s pin (descriptor store in, store out)."""
+    return vstore.end_snapshot(mv, jnp.atleast_1d(lane), jnp.array([True]))
+
+
+def begin_snapshot(st: PagedKV, lane: jax.Array) -> Tuple[PagedKV, jax.Array]:
+    mv, ts = snapshot_pin(st.mv, lane)
+    return st._replace(mv=mv), ts
 
 
 def end_snapshot(st: PagedKV, lane: jax.Array) -> PagedKV:
-    mv = vstore.end_snapshot(st.mv, jnp.atleast_1d(lane), jnp.array([True]))
-    return st._replace(mv=mv)
+    return st._replace(mv=snapshot_unpin(st.mv, lane))
 
 
 def live_pages(st: PagedKV) -> jax.Array:

@@ -25,7 +25,6 @@ allocator.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import os
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple, Union
 
@@ -36,8 +35,8 @@ import numpy as np
 from repro.ckpt.manager import CheckpointManager
 from repro.configs.base import ModelConfig, RunConfig
 from repro.core.mvgc import vstore
-from repro.core.mvgc.pool import EMPTY
-from repro.core.telemetry import GCConfig, ReclaimStats, resolve_gc_config
+from repro.core.telemetry import (EngineCounters, GCConfig, ReclaimStats,
+                                  fetch, resolve_gc_config, span)
 from repro.models import transformer as tf
 from repro.mvkv import paged
 from repro.serve.forking import ForkDAG
@@ -97,9 +96,10 @@ def decode_one(state: ServeState, cfg: ModelConfig, run: RunConfig,
     capacity gate decides — under pressure (a watermark crossed, or any lane's
     append overflowed its slab) the step reclaims *synchronously* via
     `vstore.reclaim_on_pressure` and retries the overflowed lanes in-graph;
-    otherwise the policy's normal cadence pass runs.  ``stats`` surfaces the
-    pressure accounting (reclaims, deficit, retry outcome, and the previously
-    buried ``overflow_count``/``dropped_retires`` monitors) as i32 scalars."""
+    otherwise the policy's normal cadence pass runs.  ``stats`` holds the
+    i32 monitors an operator reads: ``retry_failed`` (lanes whose retried
+    descriptor write still overflowed), and the store's running
+    ``overflow_count`` and ``dropped_retires``."""
     logits, cache = tf.decode_step(state.params, cfg, state.last_tokens,
                                    state.cache, state.cache_len,
                                    enc_out=enc_out)
@@ -115,17 +115,15 @@ def decode_one(state: ServeState, cfg: ModelConfig, run: RunConfig,
 
     def _pressure(m: vstore.MVState):
         hs = vstore.hot_slots(m, min(8, B))
-        m2, _, n = vstore.reclaim_on_pressure(
+        return vstore.reclaim_on_pressure(
             m, hs, gate.deficit, policy=run.gc.policy,
-            **run.gc.kernel_kwargs())
-        return m2, jnp.int32(1), n
+            **run.gc.kernel_kwargs())[0]
 
     def _cadence(m: vstore.MVState):
-        m2, freed_g = vstore.gc_step(m, policy=run.gc.policy,
-                                     **run.gc.kernel_kwargs())
-        return m2, jnp.int32(0), (freed_g != EMPTY).sum().astype(jnp.int32)
+        return vstore.gc_step(m, policy=run.gc.policy,
+                              **run.gc.kernel_kwargs())[0]
 
-    mv, reclaimed, n_freed = jax.lax.cond(trigger, _pressure, _cadence, mv)
+    mv = jax.lax.cond(trigger, _pressure, _cadence, mv)
 
     # retry the overflowed lanes now that the reclaim made room
     def _retry(args):
@@ -139,12 +137,7 @@ def decode_one(state: ServeState, cfg: ModelConfig, run: RunConfig,
         ovf.any(), _retry, lambda args: args, (mv, ovf))
 
     stats = {
-        "overflow_lanes": ovf.sum().astype(jnp.int32),
         "retry_failed": ovf_left.sum().astype(jnp.int32),
-        "reclaims_triggered": reclaimed,
-        "versions_reclaimed": n_freed,
-        "deficit": gate.deficit,
-        "live_versions": vstore.live_versions(mv).astype(jnp.int32),
         "overflow_count": mv.overflow_count,
         "dropped_retires": mv.dropped_retires,
     }
@@ -156,16 +149,9 @@ def decode_one(state: ServeState, cfg: ModelConfig, run: RunConfig,
 # ---------------------------------------------------------------------------
 # snapshot (rtx) interface
 # ---------------------------------------------------------------------------
-def begin_snapshot(state: ServeState, lane: jax.Array
-                   ) -> Tuple[ServeState, jax.Array]:
-    mv, ts = vstore.begin_snapshot(
-        state.mv, jnp.atleast_1d(lane), jnp.array([True]))
-    return state._replace(mv=mv), ts[0]
-
-
-def end_snapshot(state: ServeState, lane: jax.Array) -> ServeState:
-    mv = vstore.end_snapshot(state.mv, jnp.atleast_1d(lane), jnp.array([True]))
-    return state._replace(mv=mv)
+# the engines' pin and unpin programs (``jit_snapshot_pin``/``_unpin``)
+_pin = jax.jit(paged.snapshot_pin)
+_unpin = jax.jit(paged.snapshot_unpin)
 
 
 def snapshot_lengths(state: ServeState, t: jax.Array,
@@ -196,7 +182,12 @@ def snapshot_score(state: ServeState, cfg: ModelConfig, tokens: jax.Array,
 # ---------------------------------------------------------------------------
 class MVServeEngine:
     """Orchestrates jitted prefill/decode/GC with the MVGC policy, and
-    exposes the space report the benchmarks track."""
+    exposes the space report the benchmarks track.
+
+    Each call writes a ``repro.engine.*`` or ``repro.snapshot.*`` span (see
+    `repro.core.telemetry.span`), its programs run as ``jit_prefill``,
+    ``jit_decode``, ``jit_snapshot_pin``, ``jit_snapshot_unpin`` and
+    ``jit_snapshot_read``, and ``counters`` counts steps and host syncs."""
 
     def __init__(self, cfg: ModelConfig, run: RunConfig, params, batch: int,
                  max_len: int, dtype=jnp.float32):
@@ -212,18 +203,30 @@ class MVServeEngine:
         def prefill(state, tokens):
             return prefill_step(state, cfg, run, tokens)._replace(params=None)
 
+        kern = run.gc.kernel_kwargs()
+
+        def snapshot_read(state, t):
+            lens, found = snapshot_lengths(state, t, **kern)
+            return jnp.where(found, lens, 0)
+
         self._decode = jax.jit(decode)
         self._prefill = jax.jit(prefill)
+        self._read = jax.jit(snapshot_read)
+        self.counters = EngineCounters()
         self.last_stats: Dict[str, int] = {}
 
     def prefill(self, tokens: jax.Array) -> None:
-        new = self._prefill(self.state, tokens)
-        self.state = new._replace(params=self.state.params)
+        with span("repro.engine.prefill"):
+            new = self._prefill(self.state, tokens)
+            self.state = new._replace(params=self.state.params)
 
     def step(self) -> jax.Array:
-        new, toks, _, stats = self._decode(self.state)
-        self.state = new._replace(params=self.state.params)
-        self.last_stats = {k: int(v) for k, v in stats.items()}
+        with span("repro.engine.step", step=self.counters.steps):
+            new, toks, _, stats = self._decode(self.state)
+            self.state = new._replace(params=self.state.params)
+            self.last_stats = {k: int(v) for k, v in
+                               fetch(stats, self.counters).items()}
+            self.counters.steps += 1
         return toks
 
     def compile_decode(self) -> jax.stages.Compiled:
@@ -233,19 +236,28 @@ class MVServeEngine:
         return self._decode.lower(self.state).compile()
 
     def pin(self, lane: int) -> int:
-        self.state, ts = begin_snapshot(self.state, jnp.int32(lane))
-        return int(ts)
+        with span("repro.snapshot.pin"):
+            mv, ts = _pin(self.state.mv, np.int32(lane))
+            self.state = self.state._replace(mv=mv)
+            return int(fetch(ts, self.counters))
 
     def unpin(self, lane: int) -> None:
-        self.state = end_snapshot(self.state, jnp.int32(lane))
+        with span("repro.snapshot.unpin"):
+            self.state = self.state._replace(
+                mv=_unpin(self.state.mv, np.int32(lane)))
 
     def lengths_at(self, t: int) -> jax.Array:
-        lens, found = snapshot_lengths(self.state, jnp.int32(t),
-                                       **self.run.gc.kernel_kwargs())
-        return jnp.where(found, lens, 0)
+        """Every sequence's visible length at pinned time ``t`` (0 where
+        it had none)."""
+        with span("repro.snapshot.read"):
+            # the read takes the descriptors and lengths, not the weights
+            # or the cache
+            return self._read(self.state._replace(params=None, cache=None),
+                              np.int32(t))
 
     def space(self) -> Dict[str, int]:
-        return vstore.space_report(self.state.mv)
+        return {**vstore.space_report(self.state.mv),
+                **self.counters.as_row()}
 
 
 class PagedKVEngine:
@@ -268,7 +280,13 @@ class PagedKVEngine:
     Configuration lives in one :class:`repro.core.telemetry.GCConfig`
     (``gc=``); the old per-kwarg spellings (``versions_per_seq``,
     ``gc_policy``, ``page_watermark``, ...) still work for one release but
-    emit ``DeprecationWarning`` (DESIGN.md §13)."""
+    emit ``DeprecationWarning`` (DESIGN.md §13).
+
+    Every program it launches has a stable name (``jit_pool_*``,
+    ``jit_gc_*``, ``jit_snapshot_*`` in a device trace), each call writes a
+    ``repro.*`` span (`repro.core.telemetry.span`), and every device value
+    it reads on the host goes through `repro.core.telemetry.fetch`, counted
+    in ``counters.host_syncs``."""
 
     def __init__(self, num_seqs: int, num_pages: int, page_size: int,
                  max_pages_per_seq: int, kv_heads: int, head_dim: int, *,
@@ -299,24 +317,53 @@ class PagedKVEngine:
         kern = cfg.kernel_kwargs()
         self.use_kernel = kern["use_kernel"]
         self.kernel_interpret = kern["interpret"]
-        self._append = jax.jit(
-            functools.partial(paged.append_tokens, gc_policy=cfg.policy,
-                              **kern))
-        self._fork = jax.jit(
-            functools.partial(paged.fork_sequence, gc_policy=cfg.policy,
-                              copy_pages=eager_fork, **kern))
-        self._reset = jax.jit(
-            functools.partial(paged.reset_sequence, gc_policy=cfg.policy,
-                              **kern))
-        self._reclaim = jax.jit(
-            functools.partial(paged.reclaim_on_pressure, gc_policy=cfg.policy,
-                              **kern))
-        self._evict = jax.jit(paged.evict_checkpointed)
-        self._gate = jax.jit(
-            functools.partial(paged.page_pressure,
-                              watermark=cfg.page_watermark))
-        self._hot = jax.jit(functools.partial(paged.hot_sequences,
-                                              k=cfg.hot_k))
+        policy = self.gc_policy
+
+        # one named function per program, so that a device trace shows
+        # ``jit_<name>`` (a jitted ``partial`` runs as ``jit__unknown``)
+        def pool_append(st, seq_ids, k_new, v_new, mask):
+            return paged.append_tokens(st, seq_ids, k_new, v_new, mask,
+                                       gc_policy=policy, **kern)
+
+        def pool_fork(st, src_ids, dst_ids, mask):
+            return paged.fork_sequence(st, src_ids, dst_ids, mask,
+                                       gc_policy=policy,
+                                       copy_pages=eager_fork, **kern)
+
+        def pool_reset(st, seq_ids, mask):
+            return paged.reset_sequence(st, seq_ids, mask, gc_policy=policy,
+                                        **kern)
+
+        def pool_live(st):
+            return paged.live_pages(st)
+
+        def gc_gate(st):
+            return paged.page_pressure(st, watermark=cfg.page_watermark)
+
+        def gc_hot(st):
+            return paged.hot_sequences(st, k=cfg.hot_k)
+
+        def gc_reclaim(st, hot_keys, deficit):
+            return paged.reclaim_on_pressure(st, hot_keys, deficit,
+                                             gc_policy=policy, **kern)
+
+        def gc_evict(st, ckpt_max):
+            return paged.evict_checkpointed(st, ckpt_max)
+
+        def snapshot_read(st, seq_ids, t):
+            return paged.snapshot_view(st, seq_ids, t, **kern)
+
+        self._append = jax.jit(pool_append)
+        self._fork = jax.jit(pool_fork)
+        self._reset = jax.jit(pool_reset)
+        self._live = jax.jit(pool_live)
+        self._gate = jax.jit(gc_gate)
+        self._hot = jax.jit(gc_hot)
+        self._reclaim = jax.jit(gc_reclaim)
+        self._evict = jax.jit(gc_evict)
+        self._read = jax.jit(snapshot_read)
+        self._all_seqs = jnp.arange(num_seqs, dtype=jnp.int32)
+        self.counters = EngineCounters()
         self._freed_pages: List[int] = []
         self.stats = ReclaimStats(unit="pages")
         self.eager_fork = eager_fork
@@ -363,139 +410,151 @@ class PagedKVEngine:
     def releases(self) -> int:
         return self.dag.releases
 
+    def _fetch(self, tree):
+        return fetch(tree, self.counters)
+
+    def _live_pages(self) -> int:
+        return int(self._fetch(self._live(self.st)))
+
     def _note_peak(self) -> None:
-        self.stats.note_live(int(paged.live_pages(self.st)))
+        self.stats.note_live(self._live_pages())
+
+    def _note_freed(self, free_before: np.ndarray) -> None:
+        newly = np.flatnonzero(self._fetch(self.st.free) & ~free_before)
+        self._freed_pages.extend(int(p) for p in newly)
 
     def _reclaim_once(self, extra_deficit: int = 0) -> None:
-        gate = self._gate(self.st)
-        deficit = max(int(gate.deficit), extra_deficit, 1)
-        self.st, pages = self._reclaim(self.st, self._hot(self.st),
-                                       jnp.int32(deficit))
-        freed = int(pages)
-        # Checkpoint-coupled eviction (turso sole-survivor rule, DESIGN.md
-        # §14): if the policy pass left us under pressure, idle sequences
-        # whose only version is durably checkpointed are holding pages no
-        # policy can touch — current versions are always needed.  Durable
-        # storage has their data; drop them.
-        if self.ckpt_max >= 0 and bool(self._gate(self.st).under_pressure):
-            self.st, ck_pages, n_ev = self._evict(self.st,
-                                                  jnp.int32(self.ckpt_max))
-            self.stats.note_ckpt_eviction(int(n_ev), int(ck_pages))
-            freed += int(ck_pages)
-        self.stats.note_reclaim(freed, int(paged.live_pages(self.st)))
+        with span("repro.gc.reclaim"):
+            gate = self._gate(self.st)
+            deficit = max(int(self._fetch(gate.deficit)), extra_deficit, 1)
+            self.st, pages = self._reclaim(self.st, self._hot(self.st),
+                                           np.int32(deficit))
+            freed = int(self._fetch(pages))
+            # Checkpoint-coupled eviction (turso sole-survivor rule,
+            # DESIGN.md §14): if the policy pass left us under pressure,
+            # idle sequences whose only version is durably checkpointed are
+            # holding pages no policy can touch — current versions are
+            # always needed.  Durable storage has their data; drop them.
+            if self.ckpt_max >= 0 and self._fetch(
+                    self._gate(self.st).under_pressure):
+                self.st, ck_pages, n_ev = self._evict(
+                    self.st, np.int32(self.ckpt_max))
+                n_ev, ck_pages = map(int, self._fetch((n_ev, ck_pages)))
+                self.stats.note_ckpt_eviction(n_ev, ck_pages)
+                freed += ck_pages
+            self.stats.note_reclaim(freed, self._live_pages())
 
-    def step(self, seq_ids: jax.Array, k_new: jax.Array, v_new: jax.Array,
-             mask: jax.Array) -> jax.Array:
-        """Append one token per masked sequence; reclaim-and-retry on
-        pressure.  Returns failed[B] (True = gave up after reclaims)."""
-        free_before = np.asarray(self.st.free)
-        # assigned straight to self.st: a local would keep this state (and
-        # its copy of the page pool) alive through the reclaim and retry
-        self.st, failed = self._append(self.st, seq_ids, k_new, v_new, mask)
-        self._note_peak()
+    def _retrying(self, what: str, op, *args, mask: jax.Array,
+                  peak: bool = True) -> np.ndarray:
+        """Run pool program ``op(st, *args, mask)`` in a ``what`` span and
+        retry its failed lanes after a reclaim pass, up to
+        ``max_reclaim_rounds`` times, each failure a pressure event; with
+        ``peak`` the live-page peak is noted after every run.  ``failed``
+        is read once per round; returns its last host copy (the lanes
+        that gave up) with the give-ups counted."""
         rounds = 0
-        while bool(failed.any()) and rounds < self.max_reclaim_rounds:
+        while True:
+            with span(what):
+                # assigned straight to self.st: a local would keep this
+                # state (and its copy of the page pool) alive through the
+                # reclaim and retry
+                self.st, mask = op(self.st, *args, mask)
+            failed = self._fetch(mask)
+            if peak:
+                self._note_peak()
+            if not failed.any() or rounds >= self.max_reclaim_rounds:
+                break
             self.stats.note_event()
             self._reclaim_once(extra_deficit=int(failed.sum()))
-            self.st, failed = self._append(self.st, seq_ids, k_new, v_new,
-                                           failed)
-            self._note_peak()
             rounds += 1
-        # LWM rule: a watermark crossing is itself a trigger event
-        if bool(self._gate(self.st).under_pressure):
-            self.stats.note_event()
-            self._reclaim_once()
-        if bool(failed.any()):
-            self.stats.give_ups += int(failed.sum())
-        newly = np.flatnonzero(np.asarray(self.st.free) & ~free_before)
-        self._freed_pages.extend(int(p) for p in newly)
+        self.stats.give_ups += int(failed.sum())
+        return failed
+
+    def step(self, seq_ids: jax.Array, k_new: jax.Array, v_new: jax.Array,
+             mask: jax.Array) -> np.ndarray:
+        """Append one token per masked sequence; reclaim-and-retry on
+        pressure.  Returns failed[B] on the host (True = gave up after
+        reclaims)."""
+        with span("repro.engine.step", step=self.counters.steps):
+            free_before = self._fetch(self.st.free)
+            failed = self._retrying("repro.pool.append", self._append,
+                                    seq_ids, k_new, v_new, mask=mask)
+            # LWM rule: a watermark crossing is itself a trigger event
+            if self._fetch(self._gate(self.st).under_pressure):
+                self.stats.note_event()
+                self._reclaim_once()
+            self._note_freed(free_before)
+            self.counters.steps += 1
         return failed
 
     def _fork_retry(self, src_ids: jax.Array, dst_ids: jax.Array,
-                    mask: jax.Array) -> jax.Array:
+                    mask: jax.Array) -> np.ndarray:
         """The fork op proper (COW, or eager when ``eager_fork``) with the
         same reclaim-and-retry discipline as `step` — shared by `fork` and
         `join`, which differ only in lineage bookkeeping."""
-        free_before = np.asarray(self.st.free)
-        self.st, failed = self._fork(self.st, src_ids, dst_ids, mask)
-        self._note_peak()
-        rounds = 0
-        while bool(failed.any()) and rounds < self.max_reclaim_rounds:
-            self.stats.note_event()
-            self._reclaim_once(extra_deficit=int(failed.sum()))
-            self.st, failed = self._fork(self.st, src_ids, dst_ids, failed)
-            self._note_peak()
-            rounds += 1
-        if bool(failed.any()):
-            self.stats.give_ups += int(failed.sum())
-        newly = np.flatnonzero(np.asarray(self.st.free) & ~free_before)
-        self._freed_pages.extend(int(p) for p in newly)
+        free_before = self._fetch(self.st.free)
+        failed = self._retrying("repro.pool.fork", self._fork, src_ids,
+                                dst_ids, mask=mask)
+        self._note_freed(free_before)
         return failed
 
-    def _current_lengths(self, seq_ids: jax.Array) -> np.ndarray:
+    def _current_lengths(self, seq_ids: np.ndarray) -> np.ndarray:
         tbl, has = vstore.current_read(self.st.mv, jnp.asarray(seq_ids))
-        lens = np.asarray(self.st.lengths)[np.maximum(np.asarray(tbl), 0)]
-        return np.where(np.asarray(has), lens, 0)
+        tbl, has, lengths = self._fetch((tbl, has, self.st.lengths))
+        return np.where(has, lengths[np.maximum(tbl, 0)], 0)
 
     def fork(self, src_ids: jax.Array, dst_ids: jax.Array,
-             mask: jax.Array) -> jax.Array:
+             mask: jax.Array) -> np.ndarray:
         """First-class COW fork: child ``dst`` adopts parent ``src``'s
         content (sharing full pages unless ``eager_fork``) and enters the
         lineage DAG, so `joins`/`releases`/validators can see it.  Returns
         failed[B]."""
         failed = self._fork_retry(src_ids, dst_ids, mask)
-        ok = np.asarray(mask) & ~np.asarray(failed)
+        mask, src_np, dst_np = self._fetch((mask, src_ids, dst_ids))
+        ok = mask & ~failed
         if ok.any():
-            ts = int(self.st.mv.now)
-            lens = self._current_lengths(dst_ids)
-            src_np, dst_np = np.asarray(src_ids), np.asarray(dst_ids)
+            ts = int(self._fetch(self.st.mv.now))
+            lens = self._current_lengths(dst_np)
             for i in np.flatnonzero(ok):
                 self.dag.fork(int(src_np[i]), int(dst_np[i]), ts,
                               int(lens[i]))
         return failed
 
     def join(self, src_ids: jax.Array, dst_ids: jax.Array,
-             mask: jax.Array) -> jax.Array:
+             mask: jax.Array) -> np.ndarray:
         """Join child ``src`` back into ``dst``: the target adopts the
         child's content as its next descriptor version (a fork write onto
         the target slot — pages stay shared) and the child slot is released.
         Grandchildren are re-parented to the join target.  Returns
         failed[B]."""
         failed = self._fork_retry(src_ids, dst_ids, mask)
-        done = np.asarray(mask) & ~np.asarray(failed)
+        mask, src_np, dst_np = self._fetch((mask, src_ids, dst_ids))
+        done = mask & ~failed
         if done.any():
-            self.reset(jnp.asarray(src_ids), jnp.asarray(done))
-            src_np, dst_np = np.asarray(src_ids), np.asarray(dst_ids)
+            self.reset(src_np, done)
             for i in np.flatnonzero(done):
                 self.dag.join(int(src_np[i]), int(dst_np[i]))
         return failed
 
-    def release(self, seq_ids: jax.Array, mask: jax.Array) -> jax.Array:
+    def release(self, seq_ids: jax.Array, mask: jax.Array) -> np.ndarray:
         """Release a branch: recycle the slot and drop it from the lineage
         DAG — its shared pages are freed by the sweep exactly when the last
         descendant holding them goes.  Returns failed[B]."""
         failed = self.reset(seq_ids, mask)
-        done = np.asarray(mask) & ~np.asarray(failed)
-        ids_np = np.asarray(seq_ids)
-        for i in np.flatnonzero(done):
+        mask, ids_np = self._fetch((mask, seq_ids))
+        for i in np.flatnonzero(mask & ~failed):
             self.dag.release(int(ids_np[i]))
         return failed
 
-    def reset(self, seq_ids: jax.Array, mask: jax.Array) -> jax.Array:
+    def reset(self, seq_ids: jax.Array, mask: jax.Array) -> np.ndarray:
         """Recycle finished sequences' slots (empty table version); same
-        reclaim-and-retry discipline as `step`."""
-        free_before = np.asarray(self.st.free)
-        self.st, failed = self._reset(self.st, seq_ids, mask)
-        rounds = 0
-        while bool(failed.any()) and rounds < self.max_reclaim_rounds:
-            self.stats.note_event()
-            self._reclaim_once(extra_deficit=int(failed.sum()))
-            self.st, failed = self._reset(self.st, seq_ids, failed)
-            rounds += 1
-        if bool(failed.any()):
-            self.stats.give_ups += int(failed.sum())
-        newly = np.flatnonzero(np.asarray(self.st.free) & ~free_before)
-        self._freed_pages.extend(int(p) for p in newly)
+        reclaim-and-retry discipline as `step`.  Returns failed[B] on the
+        host."""
+        with span("repro.engine.reset"):
+            free_before = self._fetch(self.st.free)
+            failed = self._retrying("repro.pool.reset", self._reset,
+                                    seq_ids, mask=mask, peak=False)
+            self._note_freed(free_before)
         return failed
 
     def reclaim(self, deficit: Optional[int] = None) -> int:
@@ -506,14 +565,13 @@ class PagedKVEngine:
         if the pool is still under pressure afterwards.  Counted as one
         pressure event so the reclaims <= pressure_events invariant holds.
         Returns pages freed."""
-        free_before = np.asarray(self.st.free)
-        before = int(paged.live_pages(self.st))
+        free_before = self._fetch(self.st.free)
+        before = self._live_pages()
         self.stats.note_event()
         self._reclaim_once(
             extra_deficit=0 if deficit is None else int(deficit))
-        newly = np.flatnonzero(np.asarray(self.st.free) & ~free_before)
-        self._freed_pages.extend(int(p) for p in newly)
-        return before - int(paged.live_pages(self.st))
+        self._note_freed(free_before)
+        return before - self._live_pages()
 
     def freed_pages(self) -> List[int]:
         """Drain the handles of pages recycled since the last call — exactly
@@ -538,7 +596,7 @@ class PagedKVEngine:
         — `restore` can always bring it back."""
         mgr = (directory if isinstance(directory, CheckpointManager)
                else CheckpointManager(os.fspath(directory)))
-        ts = int(self.st.mv.now)
+        ts = int(self._fetch(self.st.mv.now))
         step = ts if step is None else int(step)
         extra = {
             "stats": dataclasses.asdict(self.stats),
@@ -574,25 +632,30 @@ class PagedKVEngine:
         return int(step)
 
     def pin(self, lane: int) -> int:
-        self.st, ts = paged.begin_snapshot(self.st, jnp.int32(lane))
-        return int(ts)
+        with span("repro.snapshot.pin"):
+            mv, ts = _pin(self.st.mv, np.int32(lane))
+            self.st = self.st._replace(mv=mv)
+            return int(self._fetch(ts))
 
     def unpin(self, lane: int) -> None:
-        self.st = paged.end_snapshot(self.st, jnp.int32(lane))
+        with span("repro.snapshot.unpin"):
+            self.st = self.st._replace(mv=_unpin(self.st.mv, np.int32(lane)))
 
     def view_at(self, t: int, seq_ids: Optional[jax.Array] = None
                 ) -> Tuple[jax.Array, jax.Array]:
+        """(page_table[B, MP], lengths[B]) of ``seq_ids`` (default: every
+        sequence) as of pinned time ``t``."""
         if seq_ids is None:
-            seq_ids = jnp.arange(self.st.mv.store.ts.shape[0],
-                                 dtype=jnp.int32)
-        return paged.snapshot_view(self.st, seq_ids, jnp.int32(t),
-                                   use_kernel=self.use_kernel,
-                                   interpret=self.kernel_interpret)
+            seq_ids = self._all_seqs
+        with span("repro.snapshot.read"):
+            # the read takes the tables and descriptors, never the pool
+            return self._read(self.st._replace(k_pages=None, v_pages=None),
+                              seq_ids, np.int32(t))
 
     def space(self) -> Dict[str, int]:
         rep = vstore.space_report(self.st.mv)
-        rep["live_pages"] = int(paged.live_pages(self.st))
-        rep["free_pages"] = int(self.st.free.sum())
+        rep["live_pages"] = self._live_pages()
+        rep["free_pages"] = self.st.free.shape[0] - rep["live_pages"]
         rep["peak_pages"] = self.peak_pages
         rep["peak_pages_post_reclaim"] = self.peak_pages_post_reclaim
         rep["pages_reclaimed"] = self.pages_reclaimed
@@ -604,4 +667,5 @@ class PagedKVEngine:
         rep["releases"] = self.releases
         rep["ckpt_evictions"] = self.stats.ckpt_evictions
         rep["ckpt_pages_freed"] = self.stats.ckpt_freed
+        rep.update(self.counters.as_row())
         return rep
