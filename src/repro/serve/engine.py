@@ -286,7 +286,13 @@ class PagedKVEngine:
     ``jit_gc_*``, ``jit_snapshot_*`` in a device trace), each call writes a
     ``repro.*`` span (`repro.core.telemetry.span`), and every device value
     it reads on the host goes through `repro.core.telemetry.fetch`, counted
-    in ``counters.host_syncs``."""
+    in ``counters.host_syncs``.
+
+    ``self.st`` is consumed by each op: the append, fork, reset, reclaim
+    and eviction programs take ownership of the state they are given
+    (donated buffers), so the page pool is updated in place and never
+    copied.  A caller must not hold ``self.st``, or any leaf of it, across
+    an op: read it afresh after each one."""
 
     def __init__(self, num_seqs: int, num_pages: int, page_size: int,
                  max_pages_per_seq: int, kv_heads: int, head_dim: int, *,
@@ -353,14 +359,16 @@ class PagedKVEngine:
         def snapshot_read(st, seq_ids, t):
             return paged.snapshot_view(st, seq_ids, t, **kern)
 
-        self._append = jax.jit(pool_append)
-        self._fork = jax.jit(pool_fork)
-        self._reset = jax.jit(pool_reset)
+        # the state outlives the programs that only read it, so only
+        # those that return it are given it donated
+        self._append = jax.jit(pool_append, donate_argnums=0)
+        self._fork = jax.jit(pool_fork, donate_argnums=0)
+        self._reset = jax.jit(pool_reset, donate_argnums=0)
         self._live = jax.jit(pool_live)
         self._gate = jax.jit(gc_gate)
         self._hot = jax.jit(gc_hot)
-        self._reclaim = jax.jit(gc_reclaim)
-        self._evict = jax.jit(gc_evict)
+        self._reclaim = jax.jit(gc_reclaim, donate_argnums=0)
+        self._evict = jax.jit(gc_evict, donate_argnums=0)
         self._read = jax.jit(snapshot_read)
         self._all_seqs = jnp.arange(num_seqs, dtype=jnp.int32)
         self.counters = EngineCounters()
@@ -455,9 +463,6 @@ class PagedKVEngine:
         rounds = 0
         while True:
             with span(what):
-                # assigned straight to self.st: a local would keep this
-                # state (and its copy of the page pool) alive through the
-                # reclaim and retry
                 self.st, mask = op(self.st, *args, mask)
             failed = self._fetch(mask)
             if peak:

@@ -1,0 +1,122 @@
+"""`PagedKVEngine` hands its state to the programs that return it: those
+programs alias the whole state (the page pool is updated in place, not
+copied), the programs that only read it alias nothing, and a state handed
+over is gone while pinned snapshots read as before."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+jax.config.update("jax_platform_name", "cpu")
+
+from repro.core.telemetry import GCConfig
+from repro.serve import engine as eng
+
+N = 4
+
+
+def _engine():
+    # 4 sequences, 8 pages of 2 tokens, 2 versions per slab: a few appends
+    # overflow the slabs, so reclaim passes run
+    return eng.PagedKVEngine(N, 8, 2, 4, 1, 4,
+                             gc=GCConfig(policy="slrt", versions_per_slot=2,
+                                         reader_lanes=2))
+
+
+def _ids():
+    return jnp.arange(N, dtype=jnp.int32)
+
+
+def _kv(step):
+    return jnp.full((N, 1, 4), float(step), jnp.float32)
+
+
+def _mask(*lanes):
+    return jnp.asarray(np.isin(np.arange(N), lanes))
+
+
+def _pool_bytes(e):
+    return e.st.k_pages.nbytes + e.st.v_pages.nbytes
+
+
+def _alias_bytes(fn, *args):
+    return fn.lower(*args).compile().memory_analysis().alias_size_in_bytes
+
+
+def _donating(e):
+    ids, kv, mask = _ids(), _kv(0), _mask(0)
+    return {
+        "_append": (e._append, (e.st, ids, kv, kv, mask)),
+        "_fork": (e._fork, (e.st, ids, ids, mask)),
+        "_reset": (e._reset, (e.st, ids, mask)),
+        "_reclaim": (e._reclaim, (e.st, e._hot(e.st), np.int32(1))),
+        "_evict": (e._evict, (e.st, np.int32(0))),
+    }
+
+
+def _reading(e):
+    return {
+        "_live": (e._live, (e.st,)),
+        "_gate": (e._gate, (e.st,)),
+        "_hot": (e._hot, (e.st,)),
+        "_read": (e._read, (e.st._replace(k_pages=None, v_pages=None),
+                            _ids(), np.int32(0))),
+    }
+
+
+@pytest.mark.parametrize("name", ["_append", "_fork", "_reset", "_reclaim",
+                                  "_evict"])
+def test_state_returning_programs_alias_the_pool(name):
+    e = _engine()
+    fn, args = _donating(e)[name]
+    assert _alias_bytes(fn, *args) >= _pool_bytes(e)
+
+
+@pytest.mark.parametrize("name", ["_live", "_gate", "_hot", "_read"])
+def test_reading_programs_alias_nothing(name):
+    e = _engine()
+    fn, args = _reading(e)[name]
+    assert _alias_bytes(fn, *args) == 0
+
+
+def test_step_consumes_the_state_it_was_given():
+    e = _engine()
+    before = e.st
+    e.step(_ids(), _kv(1), _kv(1), _mask(0, 1, 2, 3))
+    assert before.k_pages.is_deleted() and before.v_pages.is_deleted()
+    assert not e.st.k_pages.is_deleted()
+    # the new pool holds the four tokens appended, head_dim 4 each
+    assert float(jnp.abs(e.st.k_pages).sum()) == 16.0
+
+
+def test_reset_and_reclaim_consume_the_state():
+    e = _engine()
+    e.step(_ids(), _kv(1), _kv(1), _mask(0, 1))
+    before = e.st
+    e.reset(_ids(), _mask(0))
+    assert before.k_pages.is_deleted()
+    before = e.st
+    e.reclaim(deficit=8)
+    assert before.k_pages.is_deleted()
+
+
+def test_pinned_view_survives_reclaim_and_reset():
+    e = _engine()
+    ids = _ids()
+    for s in range(3):
+        e.step(ids, _kv(s + 1), _kv(s + 1), _mask(0, 1, 2, 3))
+    t = e.pin(0)
+    tables, lengths = map(np.asarray, e.view_at(t))
+    assert lengths.tolist() == [3, 3, 3, 3]
+    e.reclaim(deficit=8)
+    e.reset(ids, _mask(0, 2))
+    e.step(ids, _kv(9), _kv(9), _mask(1, 3))
+    after_t, after_l = map(np.asarray, e.view_at(t))
+    np.testing.assert_array_equal(after_t, tables)
+    np.testing.assert_array_equal(after_l, lengths)
+    # the pages the pinned view names still hold the tokens appended
+    k = np.asarray(e.st.k_pages)
+    for seq in range(N):
+        rows = [k[tables[seq, i // 2], i % 2, 0, 0] for i in range(3)]
+        assert rows == [1.0, 2.0, 3.0]
+    e.unpin(0)
