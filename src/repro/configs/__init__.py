@@ -1,4 +1,4 @@
-"""Architecture registry: the 10 assigned configs (+ reduced smoke variants).
+"""Architecture registry: the assigned configs (+ reduced smoke variants).
 
 Sources per the brief; exact dims preserved.  ``runnable(arch, shape)``
 encodes the long_500k sub-quadratic skip rules recorded in DESIGN.md §4.
@@ -86,12 +86,23 @@ RECURRENTGEMMA_9B = ModelConfig(
     rnn_width=4096, conv_width=4, act="geglu", embed_scale=True,
 )  # [arXiv:2402.19427] RG-LRU + local MQA, 2:1
 
+GRANITE_4_H_MICRO = ModelConfig(
+    name="granite-4.0-h-micro", family="hybrid",
+    num_layers=40, d_model=2048, num_heads=32, num_kv_heads=8,
+    d_ff=8192, vocab_size=100352, head_dim=64,
+    layer_pattern=("mamba2",) * 5 + ("attn",) + ("mamba2",) * 4,
+    rope=False, mamba_heads=64, mamba_head_dim=64, mamba_d_state=128,
+    mamba_groups=1, mamba_chunk=256, conv_width=4,
+    embed_mult=12.0, residual_mult=0.22, logits_div=8.0,
+    attn_scale=0.015625, norm_eps=1e-5, tie_embeddings=True,
+)  # [hf:ibm-granite/granite-4.0-h-micro] Mamba-2 + NoPE GQA at 5,15,25,35
+
 ARCHS: Dict[str, ModelConfig] = {
     c.name: c
     for c in [
         XLSTM_125M, GRANITE_MOE_1B, DEEPSEEK_MOE_16B, INTERNVL2_2B,
         MINITRON_4B, QWEN25_32B, STARCODER2_7B, GEMMA2_2B, WHISPER_TINY,
-        RECURRENTGEMMA_9B,
+        RECURRENTGEMMA_9B, GRANITE_4_H_MICRO,
     ]
 }
 

@@ -13,7 +13,10 @@ from typing import Dict, Optional, Tuple
 from repro.core.telemetry import GCConfig
 
 # block kinds understood by repro.models.blocks
-KINDS = ("attn", "local", "mlstm", "slstm", "rglru")
+KINDS = ("attn", "local", "mlstm", "slstm", "rglru", "mamba2")
+# kinds whose decode cache is a recurrent state, overwritten in place each
+# step (not a K/V history a snapshot length can cut)
+RECURRENT_KINDS = ("mlstm", "slstm", "rglru", "mamba2")
 
 
 @dataclass(frozen=True)
@@ -52,6 +55,12 @@ class ModelConfig:
     rnn_width: Optional[int] = None # rglru recurrent width (default d_model)
     mlstm_chunk: int = 64           # chunkwise-parallel training chunk
     proj_factor: float = 2.0        # mlstm block up-projection
+    # Mamba-2 (SSD) mixer; its conv width is ``conv_width``
+    mamba_heads: int = 0
+    mamba_head_dim: int = 64
+    mamba_d_state: int = 128
+    mamba_groups: int = 1           # B/C groups shared by heads
+    mamba_chunk: int = 256          # SSD chunk of the prefill scan
     # encoder-decoder (whisper)
     encoder_layers: int = 0
     encoder_tokens: int = 0         # frontend sequence length (enc input)
@@ -62,12 +71,26 @@ class ModelConfig:
     # embeddings
     tie_embeddings: bool = True
     embed_scale: bool = False       # gemma-style sqrt(d) embedding scaling
+    # granite-style multipliers; the defaults add no operation
+    embed_mult: float = 1.0         # embeddings times this
+    residual_mult: float = 1.0      # each sublayer's output times this
+    logits_div: float = 1.0         # logits over this
+    attn_scale: Optional[float] = None  # attention logit scale; 1/sqrt(hd)
     # norm
     norm_eps: float = 1e-6
 
     @property
     def hd(self) -> int:
         return self.head_dim or self.d_model // self.num_heads
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels of the Mamba-2 conv: x, B and C."""
+        return self.mamba_inner + 2 * self.mamba_groups * self.mamba_d_state
 
     @property
     def pattern_repeats(self) -> int:
@@ -91,12 +114,18 @@ class ModelConfig:
             moe += d * self.num_experts  # router
             mlp = 0
         rnn_w = self.rnn_width or d
+        di, cd, mh = self.mamba_inner, self.mamba_conv_dim, self.mamba_heads
+        # in_proj to [z, xBC, dt], conv with bias, dt_bias, A_log, D, the
+        # gated norm, out_proj
+        mamba = (d * (di + cd + mh) + cd * (self.conv_width + 1) + 3 * mh
+                 + di + di * d)
         kind_params = {
             "attn": attn + mlp + moe,
             "local": attn + mlp + moe,
             "mlstm": int(2.5 * d * int(d * self.proj_factor)) + 4 * (int(d * self.proj_factor)) * hd,
             "slstm": 4 * d * d + 4 * d * hd + d * 2 * d + mlp * 0,
             "rglru": 2 * d * rnn_w + 2 * rnn_w + rnn_w * self.conv_width + rnn_w * d + mlp,
+            "mamba2": mamba + mlp,
         }
         total = 0
         for i in range(self.num_layers):
@@ -219,6 +248,9 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
         rnn_width=64 if cfg.rnn_width else None,
         mlstm_chunk=8,
     )
+    if cfg.mamba_heads:
+        base.update(mamba_heads=4, mamba_head_dim=16, mamba_d_state=16,
+                    mamba_chunk=8)
     # keep the layer pattern but shrink repeats
     base["num_layers"] = max(len(cfg.layer_pattern), 2)
     if len(cfg.layer_pattern) == 1:

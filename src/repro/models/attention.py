@@ -65,13 +65,15 @@ def _xla_flash(
     attn_cap: float,
     q_offset: jax.Array | int = 0,
     block_s: int = 512,
+    scale: Optional[float] = None,
 ) -> jax.Array:
-    """Blockwise online-softmax attention: scan over KV blocks."""
+    """Blockwise online-softmax attention: scan over KV blocks.  ``scale``
+    multiplies the logits (1/sqrt(D) when None)."""
     B, T, Hq, D = q.shape
     S = k.shape[1]
     Hkv = k.shape[2]
     G = Hq // Hkv
-    scale = 1.0 / math.sqrt(D)
+    scale = scale or 1.0 / math.sqrt(D)
     bs = min(block_s, S)
     n_blocks = -(-S // bs)
     pad = n_blocks * bs - S
@@ -170,7 +172,8 @@ def attention(
         newv = fill_cache.v.at[bidx, idx].set(v.astype(fill_cache.v.dtype),
                                               mode="drop")
         out = _xla_flash(q, k, v, causal=causal, window=window,
-                         attn_cap=cfg.attn_softcap, q_offset=0)
+                         attn_cap=cfg.attn_softcap, q_offset=0,
+                         scale=cfg.attn_scale)
         y = jnp.einsum("bthk,hkd->btd", out, params["wo"])
         return y, KVCache(newk, newv)
 
@@ -187,7 +190,7 @@ def attention(
         # attend over the cache prefix; per-batch lengths via masking.
         # bf16 operands + f32 accumulation: reading the cache in bf16 halves
         # decode HBM traffic and stops XLA hoisting f32 cache copies.
-        scale = 1.0 / math.sqrt(D)
+        scale = cfg.attn_scale or 1.0 / math.sqrt(D)
         qf = q.reshape(B, T, Hkv, -1, D) * jnp.asarray(scale, q.dtype)
         logits = jnp.einsum("bthgd,bshd->bthgs", qf, cache.k,
                             preferred_element_type=jnp.float32)
@@ -214,6 +217,7 @@ def attention(
             window=window,
             attn_cap=cfg.attn_softcap,
             q_offset=0,
+            scale=cfg.attn_scale,
         )
 
     y = jnp.einsum("bthk,hkd->btd", out, params["wo"])

@@ -1,8 +1,8 @@
 """Residual block assembly: norm -> mixer -> (+residual) -> norm -> ffn/moe.
 
 One ``block_apply`` dispatches every mixer kind (attn/local/mlstm/slstm/
-rglru), handles gemma2 sandwich norms, decoder cross-attention, MoE aux
-losses, and the per-kind decode caches — so the whole 10-arch pool shares a
+rglru/mamba2), handles gemma2 sandwich norms, decoder cross-attention, MoE aux
+losses, and the per-kind decode caches — so the whole arch pool shares a
 single scanned superblock implementation.
 
 Local-attention decode uses a **ring cache** sized min(window, L): for
@@ -21,6 +21,9 @@ from repro.configs.base import ModelConfig
 from repro.models import attention as attn_mod
 from repro.models.attention import KVCache, attention, init_attention
 from repro.models.common import rms_norm, softcap
+from repro.models.mamba2 import (
+    init_mamba2, mamba2, mamba2_decode, mamba2_init_state,
+)
 from repro.models.mlp import init_mlp, mlp
 from repro.models.moe import init_moe, moe
 from repro.models.mlstm import (
@@ -40,8 +43,13 @@ class LocalKVCache(NamedTuple):
     pos: jax.Array   # i32[B, W] absolute position stored in each slot (-1 empty)
 
 
+def _residual(cfg: ModelConfig, h: jax.Array) -> jax.Array:
+    """A sublayer's output as it joins the residual stream."""
+    return h * cfg.residual_mult if cfg.residual_mult != 1.0 else h
+
+
 def _uses_mlp(cfg: ModelConfig, kind: str) -> bool:
-    return kind in ("attn", "local", "rglru") and (cfg.d_ff > 0 or cfg.num_experts > 0)
+    return kind in ("attn", "local", "rglru", "mamba2") and (cfg.d_ff > 0 or cfg.num_experts > 0)
 
 
 def init_block(key, cfg: ModelConfig, kind: str, dtype=jnp.float32,
@@ -57,6 +65,8 @@ def init_block(key, cfg: ModelConfig, kind: str, dtype=jnp.float32,
         p["mixer"] = init_slstm(ks[0], cfg, dtype=dtype)
     elif kind == "rglru":
         p["mixer"] = init_rglru(ks[0], cfg, dtype=dtype)
+    elif kind == "mamba2":
+        p["mixer"] = init_mamba2(ks[0], cfg, dtype=dtype)
     else:
         raise ValueError(kind)
     if cfg.post_norms:
@@ -96,6 +106,8 @@ def init_block_cache(cfg: ModelConfig, kind: str, batch: int, cache_len: int,
         return slstm_init_state(cfg, batch)
     if kind == "rglru":
         return rglru_init_state(cfg, batch)
+    if kind == "mamba2":
+        return mamba2_init_state(cfg, batch, dtype)
     raise ValueError(kind)
 
 
@@ -116,7 +128,7 @@ def _local_ring_decode(params, cfg: ModelConfig, x, positions, cache: LocalKVCac
         pos=cache.pos.at[bidx, slot].set(positions, mode="drop"),
     )
     D = q.shape[-1]
-    scale = 1.0 / math.sqrt(D)
+    scale = cfg.attn_scale or 1.0 / math.sqrt(D)
     Hkv = k.shape[2]
     qf = q.reshape(B, T, Hkv, -1, D) * jnp.asarray(scale, q.dtype)
     logits = jnp.einsum("bthgd,bshd->bthgs", qf, cache.k,
@@ -157,7 +169,7 @@ def _prefill_local_ring(params, cfg: ModelConfig, h, positions, cache: LocalKVCa
         pos=cache.pos.at[bidx, slot].set(positions, mode="drop"),
     )
     out = _xla_flash(q, k, v, causal=True, window=cfg.local_window,
-                     attn_cap=cfg.attn_softcap)
+                     attn_cap=cfg.attn_softcap, scale=cfg.attn_scale)
     return jnp.einsum("bthk,hkd->btd", out, params["wo"]), cache
 
 
@@ -202,9 +214,15 @@ def block_apply(
     elif kind == "rglru":
         fn = rglru_decode if mode == "decode" else rglru
         h, new_cache = fn(params["mixer"], cfg, h, cache)
+    elif kind == "mamba2":
+        if mode == "decode":
+            h, new_cache = mamba2_decode(params["mixer"], cfg, h, cache)
+        else:
+            # a prompt starts from a zero state, whatever the cache held
+            h, new_cache = mamba2(params["mixer"], cfg, h, cache)
     if cfg.post_norms:
         h = rms_norm(h, params["ln1_post"], cfg.norm_eps)
-    x = x + h
+    x = x + _residual(cfg, h)
 
     if "cross" in params:
         h = rms_norm(x, params["cross_ln"], cfg.norm_eps)
@@ -220,5 +238,5 @@ def block_apply(
             h = mlp(params["ffn"], cfg, h)
         if cfg.post_norms:
             h = rms_norm(h, params["ln2_post"], cfg.norm_eps)
-        x = x + h
+        x = x + _residual(cfg, h)
     return x, new_cache, aux

@@ -77,6 +77,27 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.float32) -> Dict[str, Any]:
     return params
 
 
+def _embed(params, cfg: ModelConfig, tokens) -> jax.Array:
+    x = params["embed"][tokens]
+    if cfg.embed_scale:
+        x = x * jnp.sqrt(jnp.asarray(cfg.d_model, x.dtype))
+    if cfg.embed_mult != 1.0:
+        x = x * jnp.asarray(cfg.embed_mult, x.dtype)
+    return x
+
+
+def _logits(params, cfg: ModelConfig, x) -> jax.Array:
+    """Final norm, the output head, then the logit divisor or softcap."""
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    unembed = params.get("unembed", params["embed"])
+    logits = jnp.einsum("btd,vd->btv", x, unembed)
+    if cfg.logits_div != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_div, logits.dtype)
+    if cfg.final_softcap > 0:
+        logits = softcap(logits.astype(jnp.float32), cfg.final_softcap)
+    return logits
+
+
 def param_count(params) -> int:
     return sum(x.size for x in jax.tree.leaves(params))
 
@@ -118,9 +139,7 @@ def forward(
 ) -> Tuple[jax.Array, jax.Array]:
     """Returns (logits [B, T_total, V], aux_loss)."""
     B, Tt = tokens.shape
-    x = params["embed"][tokens]
-    if cfg.embed_scale:
-        x = x * jnp.sqrt(jnp.asarray(cfg.d_model, x.dtype))
+    x = _embed(params, cfg, tokens)
 
     enc_out = None
     if _is_encdec(cfg):
@@ -150,11 +169,7 @@ def forward(
                               enc_out=enc_out)
         aux = aux + a
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    unembed = params.get("unembed", params["embed"])
-    logits = jnp.einsum("btd,vd->btv", x, unembed)
-    if cfg.final_softcap > 0:
-        logits = softcap(logits.astype(jnp.float32), cfg.final_softcap)
+    logits = _logits(params, cfg, x)
     return logits, aux
 
 
@@ -199,9 +214,7 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int, dtype=jnp.bfloat16)
 def _serve_pass(params, cfg: ModelConfig, tokens, cache, cache_len, mode,
                 enc_out=None, frontend_embeds=None, last_only=False):
     B, T = tokens.shape
-    x = params["embed"][tokens]
-    if cfg.embed_scale:
-        x = x * jnp.sqrt(jnp.asarray(cfg.d_model, x.dtype))
+    x = _embed(params, cfg, tokens)
     if (cfg.frontend != "none" and not _is_encdec(cfg)
             and frontend_embeds is not None):
         x = jnp.concatenate([frontend_embeds.astype(x.dtype), x], axis=1)
@@ -233,11 +246,7 @@ def _serve_pass(params, cfg: ModelConfig, tokens, cache, cache_len, mode,
         # prefill reads one position; a [B, T, V] logits block of a 256k
         # vocabulary would not fit beside the model
         x = x[:, -1:]
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    unembed = params.get("unembed", params["embed"])
-    logits = jnp.einsum("btd,vd->btv", x, unembed)
-    if cfg.final_softcap > 0:
-        logits = softcap(logits.astype(jnp.float32), cfg.final_softcap)
+    logits = _logits(params, cfg, x)
     return logits, {"sb": new_sb, "tail": new_tail}
 
 

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.ckpt.manager import CheckpointManager
-from repro.configs.base import ModelConfig, RunConfig
+from repro.configs.base import RECURRENT_KINDS, ModelConfig, RunConfig
 from repro.core.mvgc import vstore
 from repro.core.telemetry import (EngineCounters, GCConfig, ReclaimStats,
                                   fetch, resolve_gc_config, span)
@@ -170,7 +170,18 @@ def snapshot_lengths(state: ServeState, t: jax.Array,
 def snapshot_score(state: ServeState, cfg: ModelConfig, tokens: jax.Array,
                    t: jax.Array) -> jax.Array:
     """Score candidate tokens against the snapshot at t: attention masks use
-    the snapshot lengths, so the result is atomic w.r.t. ongoing decodes."""
+    the snapshot lengths, so the result is atomic w.r.t. ongoing decodes.
+
+    Only a cache that is a history cut by length can be read as of ``t``.
+    A recurrent state is overwritten in place each step, so a model with
+    recurrent layers is refused: it would read the current state as if it
+    were the state at ``t``."""
+    recurrent = sorted(set(cfg.layer_pattern) & set(RECURRENT_KINDS))
+    if recurrent:
+        raise ValueError(
+            f"{cfg.name}: snapshot_score cannot read the recurrent state of "
+            f"{recurrent} layers as of a pinned time; only its current "
+            f"value is held")
     lens, found = snapshot_lengths(state, t)
     lens = jnp.where(found, lens, 0)
     logits, _ = tf.decode_step(state.params, cfg, tokens, state.cache, lens)
@@ -256,8 +267,33 @@ class MVServeEngine:
                               np.int32(t))
 
     def space(self) -> Dict[str, int]:
+        """The descriptor store's space, the host counters, and the cache's
+        bytes by kind of state (`cache_bytes`)."""
         return {**vstore.space_report(self.state.mv),
-                **self.counters.as_row()}
+                **self.counters.as_row(),
+                **cache_bytes(self.cfg, self.state.cache)}
+
+
+def cache_bytes(cfg: ModelConfig, cache) -> Dict[str, int]:
+    """Bytes of the decode cache by kind of state: ``kv_cache_bytes`` (the
+    K/V, and the slot positions of windowed layers, of attention layers),
+    ``recurrent_state_bytes`` (the states recurrent layers overwrite each
+    step) and ``conv_window_bytes`` (their trailing conv inputs)."""
+    pat = cfg.layer_pattern
+    layers = [(kind, cache["sb"][f"l{i}"]) for i, kind in enumerate(pat)]
+    layers += [(pat[i % len(pat)], c) for i, c in enumerate(cache["tail"])]
+    out = {"kv_cache_bytes": 0, "recurrent_state_bytes": 0,
+           "conv_window_bytes": 0}
+    for kind, c in layers:
+        for field, leaf in zip(c._fields, c):
+            if kind not in RECURRENT_KINDS:
+                key = "kv_cache_bytes"
+            elif field == "conv":
+                key = "conv_window_bytes"
+            else:
+                key = "recurrent_state_bytes"
+            out[key] += int(leaf.size) * leaf.dtype.itemsize
+    return out
 
 
 class PagedKVEngine:

@@ -359,3 +359,81 @@ class TestKernelEdgeCases:
         want = paged_decode_ref(q, kp, vp, table, lengths)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=3e-5, rtol=1e-2)
+
+
+def _ssm_inputs(seed, Bt, H, P, N, G, state_dtype=jnp.bfloat16):
+    k = jax.random.split(jax.random.PRNGKey(seed), 7)
+    state = jax.random.normal(k[0], (Bt, N, H * P)).astype(state_dtype)
+    x = jax.random.normal(k[1], (Bt, H * P))
+    dt = jax.nn.softplus(jax.random.normal(k[2], (Bt, H)) - 3.0)
+    A = -jnp.exp(jax.random.normal(k[3], (H,)))
+    B = jax.random.normal(k[4], (Bt, G, N))
+    C = jax.random.normal(k[5], (Bt, G, N))
+    D = 1.0 + 0.1 * jax.random.normal(k[6], (H,))
+    return state, x, dt, A, B, C, D
+
+
+class TestSsmUpdateKernel:
+    """The fused Mamba-2 decode update in interpret mode vs its lax path.
+
+    Both compute the same float32 expressions; only the order of the sum
+    over ``N`` may differ, so ``y`` agrees to float32 rounding (1e-5 on
+    values of order 10), and the bfloat16 state to one bfloat16 rounding of
+    that (relative 2**-8)."""
+
+    @pytest.mark.parametrize("Bt,H,P,N,G", [(2, 4, 16, 16, 1),
+                                            (3, 8, 16, 16, 2),
+                                            (2, 64, 64, 128, 1)])
+    def test_matches_ref(self, Bt, H, P, N, G):
+        from repro.kernels.ssm_update.ops import ssm_update
+        args = _ssm_inputs(Bt * H + G, Bt, H, P, N, G)
+        y, s = ssm_update(*args, use_kernel=True, interpret=True)
+        y_ref, s_ref = ssm_update(*args, use_kernel=False)
+        assert s.dtype == jnp.bfloat16 and y.shape == (Bt, H * P)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
+                                   atol=1e-5, rtol=1e-6)
+        np.testing.assert_allclose(np.asarray(s, np.float32),
+                                   np.asarray(s_ref, np.float32),
+                                   rtol=2 ** -8, atol=1e-6)
+
+    def test_blocks_and_groups(self):
+        """Several lane blocks per group, and each group's B and C."""
+        from repro.kernels.ssm_update.kernel import ssm_update_pallas
+        from repro.kernels.ssm_update.ref import ssm_update_ref
+        Bt, H, P, N, G = 2, 8, 32, 16, 2
+        state, x, dt, A, B, C, D = _ssm_inputs(4, Bt, H, P, N, G,
+                                               jnp.float32)
+        rows = lambda v: jnp.repeat(v, P, axis=-1)
+        y, s = ssm_update_pallas(
+            state, x[:, None], rows(dt)[:, None], rows(A)[None],
+            rows(D)[None], B.reshape(-1, N, 1), C.reshape(-1, N, 1),
+            block_lanes=64, interpret=True)
+        y_ref, s_ref = ssm_update_ref(state, x, dt, A, B, C, D)
+        np.testing.assert_allclose(np.asarray(y[:, 0]), np.asarray(y_ref),
+                                   atol=1e-5, rtol=1e-6)
+        np.testing.assert_allclose(np.asarray(s), np.asarray(s_ref),
+                                   atol=1e-5, rtol=1e-6)
+
+    def test_ref_is_the_recurrence(self):
+        """The lax path against the recurrence written per head in numpy,
+        in the ``[H, P, N]`` layout."""
+        from repro.kernels.ssm_update.ref import ssm_update_ref
+        Bt, H, P, N, G = 2, 4, 8, 16, 2
+        state, x, dt, A, B, C, D = (np.asarray(a, np.float64) for a in
+                                    _ssm_inputs(7, Bt, H, P, N, G,
+                                                jnp.float32))
+        y, s = ssm_update_ref(*(jnp.asarray(a, jnp.float32) for a in
+                                (state, x, dt, A, B, C, D)))
+        S = state.reshape(Bt, N, H, P).transpose(0, 2, 3, 1)
+        for b in range(Bt):
+            for h in range(H):
+                g = h // (H // G)
+                xh = x[b, h * P:(h + 1) * P]
+                Sh = (np.exp(dt[b, h] * A[h]) * S[b, h]
+                      + dt[b, h] * np.outer(xh, B[b, g]))
+                np.testing.assert_allclose(
+                    np.asarray(y)[b, h * P:(h + 1) * P],
+                    Sh @ C[b, g] + D[h] * xh, rtol=1e-5, atol=1e-5)
+                np.testing.assert_allclose(
+                    np.asarray(s)[b].reshape(N, H, P)[:, h].T, Sh,
+                    rtol=1e-5, atol=1e-5)

@@ -7,6 +7,10 @@ cache that ``chip_smoke.py`` runs: 1024 sequences, 8 descriptor versions
 each, 8 reader lanes, 80 pages per sequence (8192 page-table versions of 81
 columns), and the retire ring's 16384-row flush sweep.
 
+The fused Mamba-2 state update compiles at the widths of the served
+``granite-4.0-h-micro`` cell: a batch of 32 sequences, 64 heads of 64
+channels, a state of 128 per channel, in bfloat16.
+
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and a test worker that loads it at
 import would stop the others from collecting.
@@ -17,6 +21,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.compact.kernel import compact_pallas, needed_pallas
+from repro.kernels.ssm_update.kernel import ssm_update_pallas
 from repro.kernels.version_search.kernel import (search_gather_pallas,
                                                  search_pallas)
 
@@ -86,3 +91,22 @@ def test_search_gather_compiles(one_chip):
         _i32(one_chip, SEQS, VERSIONS), _i32(one_chip, TABLES, MAX_PAGES + 1),
         _i32(one_chip, SEQS), _i32(one_chip, SEQS))
     assert "tpu_custom_call" in hlo
+
+
+def test_ssm_update_compiles(one_chip):
+    batch, heads, head_dim, d_state = 32, 64, 64, 128
+    lanes = heads * head_dim
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    state = jax.ShapeDtypeStruct((batch, d_state, lanes), jnp.bfloat16,
+                                 sharding=one_chip)
+    compiled = jax.jit(ssm_update_pallas, donate_argnums=0).lower(
+        state, f32(batch, 1, lanes), f32(batch, 1, lanes), f32(1, lanes),
+        f32(1, lanes), f32(batch, d_state, 1), f32(batch, d_state, 1)
+    ).compile()
+    assert "%ssm_update" in compiled.as_text()
+    # the state is written over its own buffer
+    assert compiled.memory_analysis().alias_size_in_bytes == \
+        batch * d_state * lanes * 2

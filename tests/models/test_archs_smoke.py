@@ -112,6 +112,7 @@ def test_param_counts_full_configs():
         "gemma2-2b": (2.0e9, 3.5e9),
         "whisper-tiny": (0.02e9, 0.06e9),
         "recurrentgemma-9b": (7.5e9, 11e9),
+        "granite-4.0-h-micro": (3.0e9, 3.4e9),
     }
     for name, (lo, hi) in expect.items():
         n = ARCHS[name].param_count()
@@ -150,3 +151,26 @@ def test_rglru_scan_equals_stepwise():
                                atol=1e-4, rtol=1e-3)
     np.testing.assert_allclose(np.asarray(st_scan.h), np.asarray(st.h),
                                atol=1e-4, rtol=1e-3)
+
+
+def test_mamba2_chunked_equals_stepwise():
+    """The chunked SSD prompt pass and the one-token decode step agree: a
+    prompt of 20 (two chunks of 8 and a padded one) against 20 decode steps
+    from a zero state, on every output and on the final state and window."""
+    from repro.models import mamba2 as m
+    cfg = reduced_config("granite-4.0-h-micro")
+    params = m.init_mamba2(jax.random.PRNGKey(7), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(8), (2, 20, cfg.d_model)) * 0.5
+    out_c, st_c = m.mamba2(params, cfg, x)
+    st = m.mamba2_init_state(cfg, 2, jnp.float32)
+    outs = []
+    for t in range(20):
+        o, st = m.mamba2_decode(params, cfg, x[:, t:t + 1], st)
+        outs.append(o)
+    np.testing.assert_allclose(np.asarray(out_c),
+                               np.asarray(jnp.concatenate(outs, axis=1)),
+                               atol=1e-4, rtol=1e-3)
+    np.testing.assert_allclose(np.asarray(st_c.ssm), np.asarray(st.ssm),
+                               atol=1e-4, rtol=1e-3)
+    np.testing.assert_allclose(np.asarray(st_c.conv), np.asarray(st.conv),
+                               atol=1e-6)

@@ -190,3 +190,23 @@ def test_fork_counters_dormant_zero_then_exact():
     sp = e.space()
     assert (sp["forks"], sp["joins"], sp["releases"]) == (2, 1, 1)
     assert not e.dag.nodes
+
+
+def test_snapshot_score_refuses_recurrent_layers():
+    """A recurrent state is overwritten each step: it cannot be read as of
+    a pinned time, so scoring against a snapshot is refused for a model
+    that has one, naming its recurrent kinds."""
+    cfg = reduced_config("granite-4.0-h-micro")
+    run = RunConfig(model=cfg, shape=SHAPES["decode_32k"],
+                    versions_per_slot=8, reader_lanes=4)
+    params = tf.init_params(cfg, jax.random.PRNGKey(0))
+    e = eng.MVServeEngine(cfg, run, params, batch=2, max_len=24)
+    e.prefill(jnp.ones((2, 6), jnp.int32))
+    t = e.pin(0)
+    with pytest.raises(ValueError, match="mamba2"):
+        eng.snapshot_score(e.state, cfg, jnp.ones((2, 1), jnp.int32),
+                           jnp.int32(t))
+    # pins and lengths are versioned as for any model
+    e.step()
+    assert np.asarray(e.lengths_at(t)).tolist() == [6, 6]
+    e.unpin(0)
