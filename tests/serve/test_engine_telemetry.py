@@ -80,16 +80,64 @@ def test_paged_spans_and_syncs_under_reclaim(spans):
     assert e.space()["host_syncs"] == e.counters.host_syncs
 
 
+def _count_programs(e):
+    """Wrap the engine's pool and GC programs so that each call is counted
+    by name; returns the counter."""
+    runs = collections.Counter()
+    for attr in ("_append", "_reset", "_fork", "_live", "_reclaim",
+                 "_evict"):
+        prog = getattr(e, attr)
+
+        def counted(*args, _prog=prog, _name=attr):
+            runs[_name] += 1
+            return _prog(*args)
+
+        setattr(e, attr, counted)
+    return runs
+
+
 def test_paged_step_reads_failed_once_per_round(spans):
-    """With no reclaim, a step reads the free bitmap before and after, the
-    failed lanes, the live pages and the watermark gate: five syncs."""
+    """With no reclaim and no watermark crossed, a step runs one program,
+    the append, and reads its failed lanes, live count and gate in one
+    fetch: one sync."""
     e = _paged(num_pages=64, versions=8)
+    runs = _count_programs(e)
     ids = jnp.arange(4, dtype=jnp.int32)
     kv = jnp.ones((4, 1, 4), jnp.float32)
     failed = e.step(ids, kv, kv, jnp.ones((4,), bool))
     assert isinstance(failed, np.ndarray) and not failed.any()
     assert e.stats.reclaims_triggered == 0
-    assert e.counters.host_syncs == 5
+    assert e.counters.host_syncs == 1
+    assert runs == {"_append": 1}
+    assert e.stats.peak_live == 4
+
+
+def test_paged_reclaim_step_reads_once_per_round(spans):
+    """A step that reclaims reads once, plus once per reclaim pass: each
+    retry round dispatches the reclaim and the retried append back to back
+    and reads both with one fetch; a watermark pass adds its program and
+    its read."""
+    e = _paged()
+    runs = _count_programs(e)
+    ids = jnp.arange(4, dtype=jnp.int32)
+    kv = jnp.ones((4, 1, 4), jnp.float32)
+    on = jnp.ones((4,), bool)
+    seen = 0
+    for _ in range(12):
+        syncs, passes = e.counters.host_syncs, e.stats.reclaims_triggered
+        runs.clear()
+        e.step(ids, kv, kv, on)
+        passes = e.stats.reclaims_triggered - passes
+        if not passes:
+            continue
+        seen += 1
+        assert e.counters.host_syncs - syncs == 1 + passes
+        assert runs["_reclaim"] == passes
+        # the first append, and one retry behind each pass but a
+        # watermark pass, which ends the step
+        assert passes <= runs["_append"] <= 1 + passes
+        assert set(runs) <= {"_append", "_reclaim"}
+    assert seen and e.stats.give_ups > 0
 
 
 def test_serve_spans_and_syncs(spans):
@@ -123,14 +171,14 @@ def _paged_programs(e):
     on = jnp.ones((4,), bool)
     st, i32 = e.st, np.int32(1)
     return {
-        "jit_pool_append": (e._append, (st, ids, kv, kv, on)),
-        "jit_pool_reset": (e._reset, (st, ids, on)),
-        "jit_pool_fork": (e._fork, (st, ids, ids[::-1], on)),
+        "jit_pool_append": (e._append, (st, e._freed, e._first, ids, kv,
+                                        kv, on)),
+        "jit_pool_reset": (e._reset, (st, e._freed, e._first, ids, on)),
+        "jit_pool_fork": (e._fork, (st, e._freed, e._first, ids, ids[::-1],
+                                    on)),
         "jit_pool_live": (e._live, (st,)),
-        "jit_gc_gate": (e._gate, (st,)),
-        "jit_gc_hot": (e._hot, (st,)),
-        "jit_gc_reclaim": (e._reclaim, (st, ids, i32)),
-        "jit_gc_evict": (e._evict, (st, i32)),
+        "jit_gc_reclaim": (e._reclaim, (st, e._freed, e._first, i32)),
+        "jit_gc_evict": (e._evict, (st, e._freed, i32)),
         "jit_snapshot_read": (e._read, (st._replace(k_pages=None,
                                                     v_pages=None), ids, i32)),
         "jit_snapshot_pin": (eng._pin, (st.mv, i32)),
