@@ -37,11 +37,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.mvgc.pool import EMPTY, TS_MAX
-from repro.core.telemetry import GCConfig, PressureSignal, ReclaimStats
+from repro.core.telemetry import GCConfig, PressureSignal, span
 from repro.dist.overlap import make_ring_all_reduce
 from repro.dist.sharding import host_stacked_sharding
 from repro.dist.straggler import StepWatchdog
 from repro.mvkv import paged
+from repro.serve.engine import PagedLoop
 
 
 # ---------------------------------------------------------------------------
@@ -103,15 +104,26 @@ def global_lwm(contrib: jax.Array, ring=None) -> jax.Array:
 # ---------------------------------------------------------------------------
 # sharded serving engine
 # ---------------------------------------------------------------------------
-def _per_shard(fn, mesh, axis: str):
-    """``jit`` of ``fn`` vmapped over the hosts each device holds.  Every
-    argument and result is ``[H, ...]``-leading and split over ``axis`` by
-    a ``shard_map``, so each device runs the single-host op on its own
-    shards and nothing crosses devices; the GC kernels inside could not be
-    split by XLA's partitioner anyway."""
-    spec = jax.sharding.PartitionSpec(axis)
-    return jax.jit(jax.shard_map(jax.vmap(fn), mesh=mesh, in_specs=spec,
-                                 out_specs=spec, check_vma=False))
+def _per_shard(fn, mesh, axis: str, donate: bool = True):
+    """``jit`` of ``fn`` vmapped over the hosts each device holds, under
+    ``fn``'s name.  A scalar argument is the same on every host and enters
+    replicated; every other argument, and every result, is
+    ``[H, ...]``-leading and split over ``axis`` by a ``shard_map``, so
+    each device runs the single-host op on its own shards and nothing
+    crosses devices; the GC kernels inside could not be split by XLA's
+    partitioner anyway.  With ``donate`` the first two arguments (the state
+    and the freed bits) are donated."""
+    P = jax.sharding.PartitionSpec
+
+    @functools.wraps(fn)
+    def shards(*args):
+        shared = tuple(getattr(a, "ndim", None) == 0 for a in args)
+        return jax.shard_map(
+            jax.vmap(fn, in_axes=tuple(None if s else 0 for s in shared)),
+            mesh=mesh, in_specs=tuple(P() if s else P(axis) for s in shared),
+            out_specs=P(axis), check_vma=False)(*args)
+
+    return jax.jit(shards, donate_argnums=(0, 1) if donate else ())
 
 
 def _host_slice(x: jax.Array, host: int) -> jax.Array:
@@ -126,13 +138,9 @@ def _host_slice(x: jax.Array, host: int) -> jax.Array:
     raise ValueError(f"host {host} is not held by this process")
 
 
-def _per_host(failed: jax.Array) -> np.ndarray:
-    """i32[H]: failed lanes per host, the deficit each shard must chase."""
-    return np.asarray(failed).sum(axis=1).astype(np.int32)
-
-
-class ShardedPagedKVEngine:
-    """Multi-host paged-KV serving with global-LWM reclamation.
+class ShardedPagedKVEngine(PagedLoop):
+    """`repro.serve.engine.PagedLoop` over ``hosts`` paged caches at once,
+    with global-LWM reclamation.
 
     ``hosts`` logical shards, each owning ``num_seqs`` sequences and
     ``num_pages`` pool pages, stacked along a leading ``[H]`` dim and placed
@@ -140,23 +148,29 @@ class ShardedPagedKVEngine:
     mesh of several devices the stack is built sharded, ``hosts / devices``
     shards per device, and a host count the mesh does not divide raises; on
     a one-device mesh it stays unsharded and the LWM reduction is a plain
-    ``min`` — the protocol is placement-independent.  All batched entry
-    points take ``[H, ...]``-leading arguments.
+    ``min`` — the protocol is placement-independent.  The loop's entry
+    points (`step`, `reset`, `fork`, `reclaim`, `checkpoint`, `restore`)
+    take ``[H, ...]``-leading arguments and run the single-host programs on
+    each shard (`_per_shard`), with the same donation, spans and one read
+    per round; failed lanes come back ``[H, B]`` and counts are summed over
+    hosts.  Sequence ids are host-local, so there is no fork DAG and no
+    drain of freed pages.
 
-    Every GC-bearing step first refreshes the global LWM (contributions ->
-    staleness aging -> ring-min) and threads it through the shard ops as
-    ``extra_pins``, so reclamation on any shard respects every live host's
-    pins.  Per-host :class:`StepWatchdog` instances supply the staleness
-    budget when ``gc.stale_after_s`` is inf; ``virtual_ages_s`` lets tests
-    and the dist bench inject deterministic announcement ages instead of
-    wall clock."""
+    What this engine adds to the loop: the pins.  Before an op's first
+    program and again each reclaim round it refreshes the global LWM
+    (contributions -> staleness aging -> ring-min, `lwm_pins`) and threads
+    it through the shard programs as ``extra_pins``, so reclamation on any
+    shard respects every live host's pins.  Per-host :class:`StepWatchdog`
+    instances supply the staleness budget when ``gc.stale_after_s`` is inf;
+    ``virtual_ages_s`` lets tests and the dist bench inject deterministic
+    announcement ages instead of wall clock.  Pins and snapshot reads are
+    host-indexed (`pin`, `unpin`, `view_at`, `host_state`)."""
 
     def __init__(self, hosts: int, num_seqs: int, num_pages: int,
                  page_size: int, max_pages_per_seq: int, kv_heads: int,
                  head_dim: int, *, gc: Optional[GCConfig] = None,
                  mesh=None, dtype=jnp.float32):
         cfg = gc if gc is not None else GCConfig()
-        self.gc = cfg
         self.hosts = hosts
         if mesh is None:
             from repro.launch.mesh import make_gc_mesh
@@ -180,59 +194,31 @@ class ShardedPagedKVEngine:
             # on one device
             shardings = host_stacked_sharding(jax.eval_shape(build), mesh,
                                               axis)
-            self.st = jax.jit(build, out_shardings=shardings)()
+            st = jax.jit(build, out_shardings=shardings)()
             self._ring = jax.jit(make_ring_all_reduce(mesh, axis,
                                                       reduce="min"))
         else:
-            self.st = build()
+            st = build()
             self._ring = None
+        super().__init__(st, None, cfg)
 
-        kern = cfg.kernel_kwargs()
+        def gc_gate(s):
+            return paged.page_pressure(s, watermark=cfg.page_watermark)
 
-        def _append(s, seq, k, v, m, pins):
-            return paged.append_tokens(s, seq, k, v, m,
-                                       gc_policy=cfg.policy,
-                                       extra_pins=pins, **kern)
-
-        def _reset(s, seq, m, pins):
-            return paged.reset_sequence(s, seq, m, gc_policy=cfg.policy,
-                                        extra_pins=pins, **kern)
-
-        def _fork(s, src, dst, m, pins):
-            return paged.fork_sequence(s, src, dst, m, gc_policy=cfg.policy,
-                                       extra_pins=pins, **kern)
-
-        def _reclaim(s, hot, deficit, pins):
-            return paged.reclaim_on_pressure(s, hot, deficit,
-                                             gc_policy=cfg.policy,
-                                             extra_pins=pins, **kern)
-
-        def _evict(s, ckpt, pins):
-            return paged.evict_checkpointed(s, ckpt, extra_pins=pins)
-
-        per_shard = functools.partial(_per_shard, mesh=mesh, axis=axis)
-        self._append = per_shard(_append)
-        self._reset = per_shard(_reset)
-        self._fork = per_shard(_fork)
-        self._reclaim_v = per_shard(_reclaim)
-        self._evict_v = per_shard(_evict)
-        self._gate = per_shard(functools.partial(
-            paged.page_pressure, watermark=cfg.page_watermark))
-        self._hot = per_shard(functools.partial(paged.hot_sequences,
-                                                k=cfg.hot_k))
-
+        self._gate = self._program(gc_gate, donate=False)
         self.watchdogs: List[StepWatchdog] = [StepWatchdog()
                                               for _ in range(hosts)]
         # deterministic announcement ages for tests/benches (None = fresh)
         self.virtual_ages_s: Optional[np.ndarray] = None
-        self.stats = ReclaimStats(unit="pages")
         self.lwm_advances = 0
         self._last_lwm = -1
         self.forks = 0
-        #: highest durably checkpointed timestamp across the mesh; -1 = no
-        #: checkpoint.  Arms the sole-survivor eviction rule on every shard
-        #: (DESIGN.md §14).
-        self.ckpt_max: int = -1
+
+    def _program(self, fn, donate: bool = True):
+        return _per_shard(fn, self.mesh, self.mesh.axis_names[0], donate)
+
+    def _pins(self) -> jax.Array:
+        return self.lwm_pins()
 
     # -- global LWM ----------------------------------------------------------
     def ages_s(self) -> np.ndarray:
@@ -254,14 +240,15 @@ class ShardedPagedKVEngine:
 
     def lwm_pins(self) -> jax.Array:
         """One global-LWM refresh: contributions -> staleness aging ->
-        ring-min.  Returns the per-host ``extra_pins`` array ``i32[H, 1]``
-        (every host gets the same mesh-wide LWM) and accounts
-        ``stale_lanes_aged`` / ``lwm_advances``."""
+        ring-min, read on the host with one fetch.  Returns the per-host
+        ``extra_pins`` array ``i32[H, 1]`` (every host gets the same
+        mesh-wide LWM) and accounts ``stale_lanes_aged`` /
+        ``lwm_advances``."""
         contrib = lwm_contributions(self.st)
         aged, n_aged = age_out_stale(contrib, self.ages_s(), self.budget_s())
-        self.stats.stale_lanes_aged += int(n_aged)
         lwm = global_lwm(aged, self._ring)
-        val = int(lwm)
+        n_aged, val = map(int, self._fetch((n_aged, lwm)))
+        self.stats.stale_lanes_aged += n_aged
         # an "advance" is the LWM moving up from a real pin (TS_MAX is the
         # pin-free sentinel, not a position); decreases — a new pin arriving
         # — just retrack
@@ -270,126 +257,41 @@ class ShardedPagedKVEngine:
         self._last_lwm = val
         return jnp.broadcast_to(lwm, (self.hosts, 1))
 
-    # -- accounting ----------------------------------------------------------
-    def _note_peak(self) -> None:
-        self.stats.note_live(int(self.live_pages()))
-
-    def _reclaim_once(self, pins: jax.Array, extra_deficit=0) -> None:
-        """One reclaim pass on every shard.  ``extra_deficit`` (scalar or
-        ``[H]``) is each shard's own count of failed lanes, so a shard
-        chases exactly what the single-host engine would."""
-        gate = self._gate(self.st)
-        deficit = jnp.maximum(
-            gate.deficit, np.maximum(1, extra_deficit)).astype(jnp.int32)
-        self.st, pages = self._reclaim_v(self.st, self._hot(self.st),
-                                         deficit, pins)
-        freed = int(pages.sum())
-        # checkpoint-coupled eviction (DESIGN.md §14): shards still under
-        # pressure drop idle sole-survivor sequences that durable storage
-        # already holds — pages no policy pass can reach
-        if self.ckpt_max >= 0 and bool(
-                self._gate(self.st).under_pressure.any()):
-            ck = jnp.full((self.hosts,), int(self.ckpt_max), jnp.int32)
-            self.st, ck_pages, n_ev = self._evict_v(self.st, ck, pins)
-            self.stats.note_ckpt_eviction(int(n_ev.sum()),
-                                          int(ck_pages.sum()))
-            freed += int(ck_pages.sum())
-        self.stats.note_reclaim(freed, int(self.live_pages()))
-
-    # -- batched serving ops (all args [H, ...]-leading) ---------------------
-    def step(self, seq_ids: jax.Array, k_new: jax.Array, v_new: jax.Array,
-             mask: jax.Array) -> jax.Array:
-        """Append one token per masked sequence on every host, with the
-        same reclaim-and-retry pressure discipline as ``PagedKVEngine.step``
-        — every append and reclaim carries the fresh global LWM.  Returns
-        failed[H, B]."""
-        pins = self.lwm_pins()
-        self.st, failed = self._append(self.st, seq_ids, k_new, v_new,
-                                       mask, pins)
-        self._note_peak()
-        rounds = 0
-        while bool(failed.any()) and rounds < self.gc.max_reclaim_rounds:
-            self.stats.note_event()
-            self._reclaim_once(pins, extra_deficit=_per_host(failed))
-            pins = self.lwm_pins()
-            self.st, failed = self._append(self.st, seq_ids, k_new, v_new,
-                                           failed, pins)
-            self._note_peak()
-            rounds += 1
-        if bool(self._gate(self.st).under_pressure.any()):
-            self.stats.note_event()
-            self._reclaim_once(pins)
-        if bool(failed.any()):
-            self.stats.give_ups += int(failed.sum())
-        return failed
-
-    def reset(self, seq_ids: jax.Array, mask: jax.Array) -> jax.Array:
-        """Recycle finished sequences on every host (empty table version)."""
-        pins = self.lwm_pins()
-        self.st, failed = self._reset(self.st, seq_ids, mask, pins)
-        rounds = 0
-        while bool(failed.any()) and rounds < self.gc.max_reclaim_rounds:
-            self.stats.note_event()
-            self._reclaim_once(pins, extra_deficit=_per_host(failed))
-            pins = self.lwm_pins()
-            self.st, failed = self._reset(self.st, seq_ids, failed, pins)
-            rounds += 1
-        if bool(failed.any()):
-            self.stats.give_ups += int(failed.sum())
-        return failed
-
+    # -- the loop's ops that differ -------------------------------------------
     def fork(self, src_ids: jax.Array, dst_ids: jax.Array,
-             mask: jax.Array) -> jax.Array:
-        """COW fork on every host (src and dst are host-local sequences)."""
-        pins = self.lwm_pins()
-        self.st, failed = self._fork(self.st, src_ids, dst_ids, mask, pins)
-        self._note_peak()
-        rounds = 0
-        while bool(failed.any()) and rounds < self.gc.max_reclaim_rounds:
-            self.stats.note_event()
-            self._reclaim_once(pins, extra_deficit=_per_host(failed))
-            pins = self.lwm_pins()
-            self.st, failed = self._fork(self.st, src_ids, dst_ids,
-                                         failed, pins)
-            self._note_peak()
-            rounds += 1
-        if bool(failed.any()):
-            self.stats.give_ups += int(failed.sum())
-        self.forks += int((np.asarray(mask) & ~np.asarray(failed)).sum())
+             mask: jax.Array) -> np.ndarray:
+        """COW fork on every host (src and dst are host-local sequences),
+        counted in ``forks``.  Returns failed[H, B]."""
+        failed = self._fork_retry(src_ids, dst_ids, mask)
+        self.forks += int((self._fetch(mask) & ~failed).sum())
         return failed
 
-    def reclaim(self, deficit: Optional[int] = None) -> int:
-        """Explicit GC pass on every shard against the fresh global LWM
-        (the sharded ``gc_step``).  ``deficit=None`` chases each shard's
-        gate deficit; a large explicit deficit forces the full cold-spill
-        sweep on every shard.  Returns total pages freed."""
-        pins = self.lwm_pins()
-        before = int(self.live_pages())
-        if deficit is None:
-            self._reclaim_once(pins)
-        else:
-            d = jnp.full((self.hosts,), int(deficit), jnp.int32)
-            self.st, pages = self._reclaim_v(self.st, self._hot(self.st),
-                                             d, pins)
-            self.stats.note_reclaim(int(pages.sum()),
-                                    int(self.live_pages()))
-        return before - int(self.live_pages())
+    def _extras(self) -> Dict[str, int]:
+        return {"forks": self.forks, "lwm_advances": self.lwm_advances,
+                "last_lwm": self._last_lwm}
+
+    def _load_extras(self, tree, extra: Dict[str, int]) -> None:
+        self.forks = int(extra.get("forks", 0))
+        self.lwm_advances = int(extra.get("lwm_advances", 0))
+        self._last_lwm = int(extra.get("last_lwm", -1))
 
     # -- host-local pins and snapshot reads ----------------------------------
     def pin(self, host: int, lane: int) -> int:
         """Pin ``host``'s current timestamp on its local board lane — the
         announcement never leaves the host; only the LWM reduction sees it.
         Returns the pinned timestamp."""
-        now = self.st.mv.now[host]
-        slots = self.st.mv.board.slots.at[host, lane].set(now)
-        board = self.st.mv.board._replace(slots=slots)
-        self.st = self.st._replace(mv=self.st.mv._replace(board=board))
-        return int(now)
+        with span("repro.snapshot.pin"):
+            now = self.st.mv.now[host]
+            slots = self.st.mv.board.slots.at[host, lane].set(now)
+            board = self.st.mv.board._replace(slots=slots)
+            self.st = self.st._replace(mv=self.st.mv._replace(board=board))
+            return int(self._fetch(now))
 
     def unpin(self, host: int, lane: int) -> None:
-        slots = self.st.mv.board.slots.at[host, lane].set(EMPTY)
-        board = self.st.mv.board._replace(slots=slots)
-        self.st = self.st._replace(mv=self.st.mv._replace(board=board))
+        with span("repro.snapshot.unpin"):
+            slots = self.st.mv.board.slots.at[host, lane].set(EMPTY)
+            board = self.st.mv.board._replace(slots=slots)
+            self.st = self.st._replace(mv=self.st.mv._replace(board=board))
 
     def host_state(self, host: int) -> paged.PagedKV:
         """This host's shard as a plain single-host ``PagedKV`` view, on
@@ -401,65 +303,17 @@ class ShardedPagedKVEngine:
                 ) -> Tuple[jax.Array, jax.Array]:
         """Snapshot-read ``host``'s shard at pinned time ``t`` (page tables
         + visible lengths), exactly ``paged.snapshot_view`` on the slice."""
-        # the read needs the page tables and descriptors, not the pool
-        local = jax.tree.map(lambda x: _host_slice(x, host),
-                             self.st._replace(k_pages=None, v_pages=None))
-        if seq_ids is None:
-            seq_ids = jnp.arange(local.mv.store.ts.shape[0], dtype=jnp.int32)
-        return paged.snapshot_view(local, seq_ids, jnp.int32(t),
-                                   **self.gc.kernel_kwargs())
-
-    # -- durability (DESIGN.md §14) -------------------------------------------
-    def checkpoint(self, directory, step: Optional[int] = None) -> int:
-        """Durably checkpoint the whole host-stacked pytree (every shard's
-        pages, tables, retire ring, announce board) plus the engine's
-        accounting, then advance ``ckpt_max`` to the slowest shard's clock —
-        a version is only durable mesh-wide once *every* shard has passed
-        it.  Returns the manifest step."""
-        import dataclasses
-        import os as _os
-        from repro.ckpt.manager import CheckpointManager
-        mgr = (directory if isinstance(directory, CheckpointManager)
-               else CheckpointManager(_os.fspath(directory)))
-        ts = int(jnp.min(self.st.mv.now))
-        step = ts if step is None else int(step)
-        extra = {
-            "stats": dataclasses.asdict(self.stats),
-            "forks": self.forks,
-            "lwm_advances": self.lwm_advances,
-            "last_lwm": self._last_lwm,
-            "ckpt_max": ts,
-        }
-        mgr.save(step, self.st, extra=extra)
-        self.ckpt_max = ts
-        return step
-
-    def restore(self, directory, step: Optional[int] = None) -> int:
-        """Inverse of `checkpoint`: replace the stacked pytree and replay
-        the accounting, so mesh-wide reclamation resumes where the saved
-        engine left off.  ``step=None`` restores the latest manifest."""
-        import os as _os
-        from repro.ckpt.manager import CheckpointManager
-        mgr = (directory if isinstance(directory, CheckpointManager)
-               else CheckpointManager(_os.fspath(directory)))
-        if step is None:
-            step = mgr.latest_step()
-            if step is None:
-                raise FileNotFoundError(
-                    f"no checkpoint manifest under {mgr.dir!r}")
-        tree, extra = mgr.restore(int(step), like=self.st)
-        self.st = jax.tree.map(jnp.asarray, tree)
-        self.stats = ReclaimStats(**extra.get("stats", {}))
-        self.forks = int(extra.get("forks", 0))
-        self.lwm_advances = int(extra.get("lwm_advances", 0))
-        self._last_lwm = int(extra.get("last_lwm", -1))
-        self.ckpt_max = int(extra.get("ckpt_max", -1))
-        return int(step)
+        with span("repro.snapshot.read"):
+            # the read needs the page tables and descriptors, not the pool
+            local = jax.tree.map(lambda x: _host_slice(x, host),
+                                 self.st._replace(k_pages=None,
+                                                  v_pages=None))
+            if seq_ids is None:
+                seq_ids = jnp.arange(local.mv.store.ts.shape[0],
+                                     dtype=jnp.int32)
+            return self._read(local, seq_ids, np.int32(t))
 
     # -- telemetry ------------------------------------------------------------
-    def live_pages(self) -> jax.Array:
-        return (~self.st.free).sum()
-
     def pressure(self) -> PressureSignal:
         """The unified gate over all shards: ``PressureSignal`` with
         ``[H]`` vector fields (one entry per host)."""
@@ -467,12 +321,12 @@ class ShardedPagedKVEngine:
 
     def space(self) -> Dict[str, int]:
         """Flat counters for BENCH_dist rows: the unified ReclaimStats
-        vocabulary plus the dist-only fields."""
+        vocabulary plus the dist-only fields and the host counters."""
         sig = self.pressure()
         rep = dict(self.stats.as_row())
         rep["hosts"] = self.hosts
-        rep["live_pages"] = int(self.live_pages())
         rep["free_pages"] = int(self.st.free.sum())
+        rep["live_pages"] = self.st.free.size - rep["free_pages"]
         rep["page_pool"] = int(np.prod(self.st.free.shape))
         rep["under_pressure_hosts"] = int(sig.under_pressure.sum())
         rep["lwm"] = self._last_lwm
@@ -480,4 +334,5 @@ class ShardedPagedKVEngine:
         rep["overflows"] = int(self.st.mv.overflow_count.sum())
         rep["dropped_retires"] = int(self.st.mv.dropped_retires.sum())
         rep["forks"] = self.forks
+        rep.update(self.counters.as_row())
         return rep

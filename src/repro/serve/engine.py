@@ -296,27 +296,29 @@ def cache_bytes(cfg: ModelConfig, cache) -> Dict[str, int]:
     return out
 
 
-class PagedKVEngine:
-    """Paged-KV serving loop with synchronous pressure reclamation — the
-    `freed_pages()` contract the module docstring promises, made concrete.
+def _manager(directory: Union[str, os.PathLike, CheckpointManager]
+             ) -> CheckpointManager:
+    return (directory if isinstance(directory, CheckpointManager)
+            else CheckpointManager(os.fspath(directory)))
+
+
+class PagedLoop:
+    """The paged cache's serving loop with synchronous pressure
+    reclamation, written once for every engine that runs it.
 
     ``step`` appends one token per masked sequence.  A failed append (page
     pool, table pool, or descriptor slab exhausted) is a **pressure event**:
-    the engine reclaims synchronously — hot-sequence-first descriptor
-    compaction, then the reachability sweep that recycles pages — and retries
-    the failed lanes, up to ``max_reclaim_rounds`` before giving up (turso's
-    trigger-on-event rule; the sim's abort => reclaim => retry loop).  A
-    post-step watermark crossing triggers the same pass without a failure.
-    Accounting lives in one :class:`repro.core.telemetry.ReclaimStats`
-    (``self.stats``); the schema-v4 counter names (``pressure_events``,
-    ``reclaims_triggered``, ``pages_reclaimed``, ``peak_pages``,
-    ``peak_pages_post_reclaim``, ``give_ups``) survive as read-only
-    properties feeding BENCH_serve rows directly.
-
-    Configuration lives in one :class:`repro.core.telemetry.GCConfig`
-    (``gc=``); the old per-kwarg spellings (``versions_per_seq``,
-    ``gc_policy``, ``page_watermark``, ...) still work for one release but
-    emit ``DeprecationWarning`` (DESIGN.md §13).
+    the loop reclaims synchronously — hot-sequence-first descriptor
+    compaction, then the reachability sweep that recycles pages — and
+    retries the failed lanes, up to ``gc.max_reclaim_rounds`` before giving
+    up (turso's trigger-on-event rule; the sim's abort => reclaim => retry
+    loop).  A post-step watermark crossing triggers the same pass without a
+    failure.  Accounting lives in one
+    :class:`repro.core.telemetry.ReclaimStats` (``self.stats``); the
+    schema-v4 counter names (``pressure_events``, ``reclaims_triggered``,
+    ``pages_reclaimed``, ``peak_pages``, ``peak_pages_post_reclaim``,
+    ``give_ups``) survive as read-only properties feeding BENCH_serve rows
+    directly.
 
     Every program it launches has a stable name (``jit_pool_*``,
     ``jit_gc_*``, ``jit_snapshot_*`` in a device trace), each call writes a
@@ -324,57 +326,41 @@ class PagedKVEngine:
     it reads on the host goes through `repro.core.telemetry.fetch`, counted
     in ``counters.host_syncs``.
 
-    Each state-changing program returns, beside the state, what the host
-    loop needs to choose its next move: the failed lanes, the live page
-    count and the watermark gate of the state it leaves.  The host reads
-    them with one fetch per round: a step with nothing failed and no
-    watermark crossed runs one program and reads once; each reclaim round
-    adds the reclaim program and the retried op, dispatched back to back,
-    and one read.  The programs also fold the pages they free into a
-    device bitmap that only `freed_pages()` reads.
+    Each state-changing program returns, beside the state, what the loop
+    needs to choose its next move: the failed lanes, the live page count
+    and the watermark gate of the state it leaves.  The loop reads them
+    with one fetch per round: a step with nothing failed and no watermark
+    crossed runs one program and reads once; each reclaim round adds the
+    reclaim program and the retried op, dispatched back to back, and one
+    read.  Reports are read with ``[..., :]`` indexing, so a state whose
+    every leaf has a leading host dim, and whose reports are ``[H, ...]``,
+    runs the same loop: counts are summed over hosts, and a gate crossed on
+    any host is crossed.
 
     ``self.st`` is consumed by each op: the append, fork, reset, reclaim
     and eviction programs take ownership of the state they are given
     (donated buffers), so the page pool is updated in place and never
     copied.  A caller must not hold ``self.st``, or any leaf of it, across
-    an op: read it afresh after each one."""
+    an op: read it afresh after each one.
 
-    def __init__(self, num_seqs: int, num_pages: int, page_size: int,
-                 max_pages_per_seq: int, kv_heads: int, head_dim: int, *,
-                 gc: Optional[GCConfig] = None,
-                 versions_per_seq: Optional[int] = None,
-                 reader_lanes: Optional[int] = None,
-                 ring_capacity: Optional[int] = None,
-                 gc_policy: Optional[str] = None,
-                 page_watermark: Optional[float] = None,
-                 hot_k: Optional[int] = None,
-                 max_reclaim_rounds: Optional[int] = None,
-                 use_kernel: Optional[bool] = None,
-                 kernel_interpret: Optional[bool] = None,
-                 eager_fork: bool = False, dtype=jnp.float32):
-        cfg = resolve_gc_config(
-            gc, "PagedKVEngine",
-            versions_per_slot=versions_per_seq, reader_lanes=reader_lanes,
-            ring_capacity=ring_capacity, policy=gc_policy,
-            page_watermark=page_watermark, hot_k=hot_k,
-            max_reclaim_rounds=max_reclaim_rounds, use_kernel=use_kernel,
-            kernel_interpret=kernel_interpret)
-        self.gc = cfg
-        self.st = paged.make_paged_kv(
-            num_seqs, num_pages, page_size, max_pages_per_seq, kv_heads,
-            head_dim, gc=cfg, dtype=dtype)
-        self.gc_policy = cfg.policy
-        self.max_reclaim_rounds = cfg.max_reclaim_rounds
-        kern = cfg.kernel_kwargs()
-        self.use_kernel = kern["use_kernel"]
-        self.kernel_interpret = kern["interpret"]
-        policy = self.gc_policy
+    An engine built on the loop gives it the state, the freed-page bits the
+    programs fold (or None, for none) and its `GCConfig`; it may change how
+    a program is compiled (`_program`), the pins every program honours
+    beside the state's own board (`_pins`) and what a checkpoint holds
+    beside the state (`_extras`, `_load_extras`)."""
+
+    def __init__(self, st, freed, gc: GCConfig, eager_fork: bool = False):
+        self.st, self._freed, self.gc = st, freed, gc
+        kern = gc.kernel_kwargs()
+        policy = gc.policy
 
         def opened(st, freed, first):
             """The freed-since-drain bits (pages freed by earlier ops, the
             free bitmap at the current op's start) as a program leaves
             them: the first program of an engine op folds in what the op
             before it freed, end against start, and notes the new start."""
+            if freed is None:
+                return None
             acc, start = freed
             return (jnp.where(first, acc | (st.free & ~start), acc),
                     jnp.where(first, st.free, start))
@@ -385,7 +371,7 @@ class PagedKVEngine:
             i32[B + 2], the failed lanes, then the live count and the
             watermark gate of the state left."""
             out, failed = out_failed
-            gate = paged.page_pressure(out, watermark=cfg.page_watermark)
+            gate = paged.page_pressure(out, watermark=gc.page_watermark)
             report = jnp.concatenate([
                 failed.astype(jnp.int32),
                 jnp.stack([gate.live, gate.under_pressure.astype(jnp.int32)])])
@@ -393,74 +379,91 @@ class PagedKVEngine:
                     report)
 
         # one named function per program, so that a device trace shows
-        # ``jit_<name>`` (a jitted ``partial`` runs as ``jit__unknown``)
-        def pool_append(st, freed, first, seq_ids, k_new, v_new, mask):
+        # ``jit_<name>`` (a jitted ``partial`` runs as ``jit__unknown``);
+        # ``pins`` are the ``extra_pins`` of `_pins`
+        def pool_append(st, freed, first, seq_ids, k_new, v_new, mask,
+                        pins=None):
             return op_result(st, freed, first, paged.append_tokens(
-                st, seq_ids, k_new, v_new, mask, gc_policy=policy, **kern))
+                st, seq_ids, k_new, v_new, mask, gc_policy=policy,
+                extra_pins=pins, **kern))
 
-        def pool_fork(st, freed, first, src_ids, dst_ids, mask):
+        def pool_fork(st, freed, first, src_ids, dst_ids, mask, pins=None):
             return op_result(st, freed, first, paged.fork_sequence(
                 st, src_ids, dst_ids, mask, gc_policy=policy,
-                copy_pages=eager_fork, **kern))
+                copy_pages=eager_fork, extra_pins=pins, **kern))
 
-        def pool_reset(st, freed, first, seq_ids, mask):
+        def pool_reset(st, freed, first, seq_ids, mask, pins=None):
             return op_result(st, freed, first, paged.reset_sequence(
-                st, seq_ids, mask, gc_policy=policy, **kern))
+                st, seq_ids, mask, gc_policy=policy, extra_pins=pins,
+                **kern))
 
         def pool_live(st):
             return paged.live_pages(st)
 
-        def gc_reclaim(st, freed, first, extra):
+        def gc_reclaim(st, freed, first, extra, pins=None):
             """One reclaim pass: the gate's deficit, raised to ``extra``
             (the failed lanes that called it) and to 1, chased from the
             hot sequences.  Returns (state, freed bits, report): i32[3],
             the pages freed, then the live count and the watermark gate of
             the state left."""
-            gate = paged.page_pressure(st, watermark=cfg.page_watermark)
+            gate = paged.page_pressure(st, watermark=gc.page_watermark)
             deficit = jnp.maximum(jnp.maximum(gate.deficit, extra), 1)
             out, pages = paged.reclaim_on_pressure(
-                st, paged.hot_sequences(st, k=cfg.hot_k), deficit,
-                gc_policy=policy, **kern)
-            after = paged.page_pressure(out, watermark=cfg.page_watermark)
+                st, paged.hot_sequences(st, k=gc.hot_k), deficit,
+                gc_policy=policy, extra_pins=pins, **kern)
+            after = paged.page_pressure(out, watermark=gc.page_watermark)
             return out, opened(st, freed, first), jnp.stack([
                 pages, after.live, after.under_pressure.astype(jnp.int32)])
 
-        def gc_evict(st, freed, ckpt_max):
+        def gc_evict(st, freed, ckpt_max, pins=None):
             """Returns (state, freed bits, report): i32[3], the pages
             freed, the versions evicted and the live count of the state
             left.  It runs only behind a reclaim pass, never first."""
-            out, pages, n_ev = paged.evict_checkpointed(st, ckpt_max)
+            out, pages, n_ev = paged.evict_checkpointed(st, ckpt_max, pins)
             return out, freed, jnp.stack([pages, n_ev, paged.live_pages(out)])
 
         def snapshot_read(st, seq_ids, t):
             return paged.snapshot_view(st, seq_ids, t, **kern)
 
-        # the state outlives the programs that only read it, so only
-        # those that return it are given it, and the freed bits, donated
-        self._append = jax.jit(pool_append, donate_argnums=(0, 1))
-        self._fork = jax.jit(pool_fork, donate_argnums=(0, 1))
-        self._reset = jax.jit(pool_reset, donate_argnums=(0, 1))
-        self._live = jax.jit(pool_live)
-        self._reclaim = jax.jit(gc_reclaim, donate_argnums=(0, 1))
-        self._evict = jax.jit(gc_evict, donate_argnums=(0, 1))
+        self._append = self._program(pool_append)
+        self._fork = self._program(pool_fork)
+        self._reset = self._program(pool_reset)
+        self._live = self._program(pool_live, donate=False)
+        self._reclaim = self._program(gc_reclaim)
+        self._evict = self._program(gc_evict)
+        # a snapshot read is of one host's tables
         self._read = jax.jit(snapshot_read)
-        self._all_seqs = jnp.arange(num_seqs, dtype=jnp.int32)
         self.counters = EngineCounters()
-        #: (pages freed by ops since the last `freed_pages()`, the free
-        #: bitmap at the current op's start), bool[num_pages] each: donated
-        #: with the state, read only by `freed_pages()` and `checkpoint()`.
-        #: An all-free start counts nothing freed before the first op.
-        self._freed = self._drained(np.ones(num_pages, bool))
         # whether a program is the first of an engine op
         self._first, self._later = (jax.device_put(np.bool_(b))
                                     for b in (True, False))
         self.stats = ReclaimStats(unit="pages")
-        self.eager_fork = eager_fork
-        self.dag = ForkDAG()
         #: highest durably checkpointed timestamp; -1 = no checkpoint taken.
         #: Setting it (via `checkpoint()`) arms the sole-survivor eviction
-        #: rule in `_reclaim_once` (DESIGN.md §14).
+        #: rule in `_reclaim_pass` (DESIGN.md §14).
         self.ckpt_max: int = -1
+
+    def _program(self, fn, donate: bool = True):
+        """The program that runs ``fn``.  With ``donate`` it takes
+        ownership of its first two arguments, the state and the freed bits;
+        the state outlives the programs that only read it."""
+        return jax.jit(fn, donate_argnums=(0, 1) if donate else ())
+
+    def _pins(self):
+        """The ``extra_pins`` the programs of an op honour, taken before
+        its first program and again each reclaim round.  On one host the
+        board holds every pin: None, an empty pytree, adds nothing to a
+        program's arguments or its HLO."""
+        return None
+
+    def _extras(self) -> Dict[str, Any]:
+        """What a checkpoint's manifest holds beside ``stats`` and
+        ``ckpt_max``."""
+        return {}
+
+    def _load_extras(self, tree, extra: Dict[str, Any]) -> None:
+        """Replay `_extras` from a restored manifest; ``tree`` is the
+        restored state on the host."""
 
     # schema-v4 counter names, now backed by the unified ReclaimStats
     @property
@@ -487,6 +490,229 @@ class PagedKVEngine:
     def peak_pages_post_reclaim(self) -> int:
         return self.stats.peak_live_post_reclaim
 
+    def _fetch(self, tree):
+        return fetch(tree, self.counters)
+
+    def _run(self, what: str, op, first: jax.Array, pins, *args
+             ) -> List[jax.Array]:
+        """Dispatch pool program ``op`` in a ``what`` span; returns its
+        device (failed, failed count, report)."""
+        with span(what):
+            self.st, self._freed, *out = op(self.st, self._freed, first,
+                                            *args, pins)
+        return out
+
+    def _reclaim_pass(self, extra, first: jax.Array, pins
+                      ) -> Tuple[jax.Array, Optional[jax.Array]]:
+        """Dispatch one reclaim pass, chasing at least ``extra`` pages, in a
+        ``repro.gc.reclaim`` span; returns its device reports (the pass's,
+        the eviction's or None) for `_note_reclaim`, read with the round's
+        fetch.
+
+        Checkpoint-coupled eviction (turso sole-survivor rule, DESIGN.md
+        §14): if the policy pass left the pool under pressure, idle
+        sequences whose only version is durably checkpointed hold pages no
+        policy can touch (current versions are always needed).  Durable
+        storage has their data; drop them.  Deciding that takes a read of
+        the gate of its own, made only while a checkpoint is armed."""
+        with span("repro.gc.reclaim"):
+            self.st, self._freed, report = self._reclaim(
+                self.st, self._freed, first, extra, pins)
+            if self.ckpt_max >= 0 and self._fetch(report)[..., 2].any():
+                self.st, self._freed, evicted = self._evict(
+                    self.st, self._freed, np.int32(self.ckpt_max), pins)
+                return report, evicted
+            return report, None
+
+    def _note_reclaim(self, report, evicted) -> int:
+        """Count one reclaim pass from its fetched reports; returns the
+        pages it freed."""
+        freed, live, _ = np.reshape(report, (-1, 3)).sum(axis=0)
+        if evicted is not None:
+            ck_pages, n_ev, live = np.reshape(evicted, (-1, 3)).sum(axis=0)
+            self.stats.note_ckpt_eviction(int(n_ev), int(ck_pages))
+            freed += ck_pages
+        self.stats.note_reclaim(int(freed), int(live))
+        return int(freed)
+
+    def _retrying(self, what: str, op, *args, mask: jax.Array,
+                  peak: bool = True, watermark: bool = False) -> np.ndarray:
+        """Run pool program ``op(st, freed, first, *args, mask, pins)`` in a
+        ``what`` span and retry its failed lanes after a reclaim pass, up to
+        ``max_reclaim_rounds`` times, each failure a pressure event; with
+        ``peak`` the live-page peak is noted after every run.  A round is
+        one fetch: the reclaim pass and the retried op are dispatched back
+        to back, on the device's failed mask and count.  With
+        ``watermark``, a state left under the page watermark is itself a
+        trigger event: one more pass runs, and reads once.  Returns the
+        last failed lanes on the host (those that gave up, counted)."""
+        pins = self._pins()
+        failed, n_failed, report = self._run(what, op, self._first, pins,
+                                             *args, mask)
+        passed = None
+        rounds = 0
+        while True:
+            report_h, passed_h = self._fetch((report, passed))
+            failed_h = report_h[..., :-2].astype(bool)
+            if passed_h is not None:
+                self._note_reclaim(*passed_h)
+            if peak:
+                self.stats.note_live(int(report_h[..., -2].sum()))
+            if not failed_h.any() or rounds >= self.gc.max_reclaim_rounds:
+                break
+            self.stats.note_event()
+            pins = self._pins()
+            passed = self._reclaim_pass(n_failed, self._later, pins)
+            failed, n_failed, report = self._run(what, op, self._later,
+                                                 pins, *args, failed)
+            rounds += 1
+        self.stats.give_ups += int(failed_h.sum())
+        # LWM rule: a watermark crossing is itself a trigger event
+        if watermark and report_h[..., -1].any():
+            self.stats.note_event()
+            self._note_reclaim(*self._fetch(
+                self._reclaim_pass(np.int32(0), self._later, pins)))
+        return failed_h
+
+    def step(self, seq_ids: jax.Array, k_new: jax.Array, v_new: jax.Array,
+             mask: jax.Array) -> np.ndarray:
+        """Append one token per masked sequence; reclaim-and-retry on
+        pressure.  Returns failed, shaped as ``mask``, on the host (True =
+        gave up after reclaims)."""
+        with span("repro.engine.step", step=self.counters.steps):
+            failed = self._retrying("repro.pool.append", self._append,
+                                    seq_ids, k_new, v_new, mask=mask,
+                                    watermark=True)
+            self.counters.steps += 1
+        return failed
+
+    def _fork_retry(self, src_ids: jax.Array, dst_ids: jax.Array,
+                    mask: jax.Array) -> np.ndarray:
+        """The fork op proper (COW, or eager when ``eager_fork``) with the
+        same reclaim-and-retry discipline as `step`."""
+        return self._retrying("repro.pool.fork", self._fork, src_ids,
+                              dst_ids, mask=mask)
+
+    def reset(self, seq_ids: jax.Array, mask: jax.Array) -> np.ndarray:
+        """Recycle finished sequences' slots (empty table version); same
+        reclaim-and-retry discipline as `step`.  Returns failed on the
+        host."""
+        with span("repro.engine.reset"):
+            failed = self._retrying("repro.pool.reset", self._reset,
+                                    seq_ids, mask=mask, peak=False)
+            # The live count a reset leaves, as a program of its own and
+            # never read: the benchmark's trace test
+            # (tests/chipbench/test_chipbench_program_trace.py) expects
+            # ``jit_pool_live`` among an engine call's programs.  It costs a
+            # reset one small program and no host read.
+            self._live(self.st)
+        return failed
+
+    def reclaim(self, deficit: Optional[int] = None) -> int:
+        """Explicit GC pass (the engine-level ``gc_step``): chases the gate
+        deficit, or an explicit one — a large deficit forces the full
+        cold-spill sweep — and with ``ckpt_max`` armed the
+        checkpoint-eviction post-pass runs if the pool is still under
+        pressure afterwards.  Counted as one pressure event so the
+        reclaims <= pressure_events invariant holds.  Returns pages
+        freed."""
+        self.stats.note_event()
+        return self._note_reclaim(*self._fetch(self._reclaim_pass(
+            np.int32(0 if deficit is None else deficit), self._first,
+            self._pins())))
+
+    # -- durability (DESIGN.md §14) -------------------------------------
+    def checkpoint(self, directory: Union[str, os.PathLike,
+                                          CheckpointManager],
+                   step: Optional[int] = None) -> int:
+        """Durably checkpoint the whole engine: the state pytree (pages,
+        free bitmaps, page tables, the full MVState including the retire
+        ring and announce board) plus the host-side GC state (ReclaimStats
+        and the engine's `_extras`).  Returns the manifest step.
+
+        Success *arms* the sole-survivor rule: ``ckpt_max`` advances to the
+        store clock — the slowest host's, as a version is durable
+        everywhere only once every host has passed it — so every version
+        written up to now is durable and an idle sequence's sole surviving
+        version may be evicted under pressure: `restore` can always bring
+        it back."""
+        mgr = _manager(directory)
+        ts = int(np.min(self._fetch(self.st.mv.now)))
+        step = ts if step is None else int(step)
+        extra = {"stats": dataclasses.asdict(self.stats), **self._extras(),
+                 "ckpt_max": ts}
+        mgr.save(step, self.st, extra=extra)
+        self.ckpt_max = ts
+        return step
+
+    def restore(self, directory: Union[str, os.PathLike, CheckpointManager],
+                step: Optional[int] = None) -> int:
+        """Inverse of `checkpoint`: replace the device pytree and replay the
+        host-side GC state (retire ring and announce board ride in the
+        pytree; stats and the engine's extras come from the manifest), so
+        reclamation resumes exactly where the saved engine left off.
+        ``step=None`` restores the latest manifest."""
+        mgr = _manager(directory)
+        if step is None:
+            step = mgr.latest_step()
+            if step is None:
+                raise FileNotFoundError(
+                    f"no checkpoint manifest under {mgr.dir!r}")
+        tree, extra = mgr.restore(int(step), like=self.st)
+        self.st = jax.tree_util.tree_map(jnp.asarray, tree)
+        self.stats = ReclaimStats(**extra.get("stats", {}))
+        self.ckpt_max = int(extra.get("ckpt_max", -1))
+        self._load_extras(tree, extra)
+        return int(step)
+
+
+class PagedKVEngine(PagedLoop):
+    """`PagedLoop` over one host's paged cache — the `freed_pages()`
+    contract the module docstring promises, made concrete.  Beside the
+    loop it keeps what only one host has: the fork lineage DAG (`fork`,
+    `join`, `release`), the drain of freed pages (the pool programs fold
+    the pages they free into a device bitmap that only `freed_pages()` and
+    `checkpoint()` read), snapshot pins by lane (`pin`, `unpin`,
+    `view_at`) and its `space()` row.
+
+    Configuration lives in one :class:`repro.core.telemetry.GCConfig`
+    (``gc=``); the old per-kwarg spellings (``versions_per_seq``,
+    ``gc_policy``, ``page_watermark``, ...) still work for one release but
+    emit ``DeprecationWarning`` (DESIGN.md §13)."""
+
+    def __init__(self, num_seqs: int, num_pages: int, page_size: int,
+                 max_pages_per_seq: int, kv_heads: int, head_dim: int, *,
+                 gc: Optional[GCConfig] = None,
+                 versions_per_seq: Optional[int] = None,
+                 reader_lanes: Optional[int] = None,
+                 ring_capacity: Optional[int] = None,
+                 gc_policy: Optional[str] = None,
+                 page_watermark: Optional[float] = None,
+                 hot_k: Optional[int] = None,
+                 max_reclaim_rounds: Optional[int] = None,
+                 use_kernel: Optional[bool] = None,
+                 kernel_interpret: Optional[bool] = None,
+                 eager_fork: bool = False, dtype=jnp.float32):
+        cfg = resolve_gc_config(
+            gc, "PagedKVEngine",
+            versions_per_slot=versions_per_seq, reader_lanes=reader_lanes,
+            ring_capacity=ring_capacity, policy=gc_policy,
+            page_watermark=page_watermark, hot_k=hot_k,
+            max_reclaim_rounds=max_reclaim_rounds, use_kernel=use_kernel,
+            kernel_interpret=kernel_interpret)
+        # the freed bits: (pages freed by ops since the last
+        # `freed_pages()`, the free bitmap at the current op's start),
+        # bool[num_pages] each.  An all-free start counts nothing freed
+        # before the first op.
+        super().__init__(
+            paged.make_paged_kv(num_seqs, num_pages, page_size,
+                                max_pages_per_seq, kv_heads, head_dim,
+                                gc=cfg, dtype=dtype),
+            self._drained(np.ones(num_pages, bool)), cfg, eager_fork)
+        self.gc_policy = cfg.policy
+        self._all_seqs = jnp.arange(num_seqs, dtype=jnp.int32)
+        self.dag = ForkDAG()
+
     @property
     def forks(self) -> int:
         return self.dag.forks
@@ -498,9 +724,6 @@ class PagedKVEngine:
     @property
     def releases(self) -> int:
         return self.dag.releases
-
-    def _fetch(self, tree):
-        return fetch(tree, self.counters)
 
     @staticmethod
     def _drained(start: np.ndarray, pending=()
@@ -520,104 +743,6 @@ class PagedKVEngine:
 
     def _live_pages(self) -> int:
         return int(self._fetch(self._live(self.st)))
-
-    def _run(self, what: str, op, first: jax.Array, *args
-             ) -> List[jax.Array]:
-        """Dispatch pool program ``op`` in a ``what`` span; returns its
-        device (failed, failed count, report)."""
-        with span(what):
-            self.st, self._freed, *out = op(self.st, self._freed, first,
-                                            *args)
-        return out
-
-    def _reclaim_pass(self, extra, first: jax.Array
-                      ) -> Tuple[jax.Array, Optional[jax.Array]]:
-        """Dispatch one reclaim pass, chasing at least ``extra`` pages, in a
-        ``repro.gc.reclaim`` span; returns its device reports (the pass's,
-        the eviction's or None) for `_note_reclaim`, read with the round's
-        fetch.
-
-        Checkpoint-coupled eviction (turso sole-survivor rule, DESIGN.md
-        §14): if the policy pass left the pool under pressure, idle
-        sequences whose only version is durably checkpointed hold pages no
-        policy can touch (current versions are always needed).  Durable
-        storage has their data; drop them.  Deciding that takes a read of
-        the gate of its own, made only while a checkpoint is armed."""
-        with span("repro.gc.reclaim"):
-            self.st, self._freed, report = self._reclaim(
-                self.st, self._freed, first, extra)
-            if self.ckpt_max >= 0 and self._fetch(report)[2]:
-                self.st, self._freed, evicted = self._evict(
-                    self.st, self._freed, np.int32(self.ckpt_max))
-                return report, evicted
-            return report, None
-
-    def _note_reclaim(self, report, evicted) -> int:
-        """Count one reclaim pass from its fetched reports; returns the
-        pages it freed."""
-        freed, live = int(report[0]), int(report[1])
-        if evicted is not None:
-            ck_pages, n_ev, live = map(int, evicted)
-            self.stats.note_ckpt_eviction(n_ev, ck_pages)
-            freed += ck_pages
-        self.stats.note_reclaim(freed, live)
-        return freed
-
-    def _retrying(self, what: str, op, *args, mask: jax.Array,
-                  peak: bool = True) -> Tuple[np.ndarray, bool]:
-        """Run pool program ``op(st, freed, first, *args, mask)`` in a
-        ``what`` span and retry its failed lanes after a reclaim pass, up to
-        ``max_reclaim_rounds`` times, each failure a pressure event; with
-        ``peak`` the live-page peak is noted after every run.  A round is
-        one fetch: the reclaim pass and the retried op are dispatched back
-        to back, on the device's failed mask and count.  Returns the last
-        failed lanes on the host (those that gave up, counted) and whether
-        the state left is under the page watermark."""
-        failed, n_failed, report = self._run(what, op, self._first, *args,
-                                             mask)
-        passed = None
-        rounds = 0
-        while True:
-            report_h, passed_h = self._fetch((report, passed))
-            failed_h = report_h[:-2].astype(bool)
-            if passed_h is not None:
-                self._note_reclaim(*passed_h)
-            if peak:
-                self.stats.note_live(report_h[-2])
-            if not failed_h.any() or rounds >= self.max_reclaim_rounds:
-                break
-            self.stats.note_event()
-            passed = self._reclaim_pass(n_failed, self._later)
-            failed, n_failed, report = self._run(what, op, self._later,
-                                                 *args, failed)
-            rounds += 1
-        self.stats.give_ups += int(failed_h.sum())
-        return failed_h, bool(report_h[-1])
-
-    def step(self, seq_ids: jax.Array, k_new: jax.Array, v_new: jax.Array,
-             mask: jax.Array) -> np.ndarray:
-        """Append one token per masked sequence; reclaim-and-retry on
-        pressure.  Returns failed[B] on the host (True = gave up after
-        reclaims)."""
-        with span("repro.engine.step", step=self.counters.steps):
-            failed, pressure = self._retrying(
-                "repro.pool.append", self._append, seq_ids, k_new, v_new,
-                mask=mask)
-            # LWM rule: a watermark crossing is itself a trigger event
-            if pressure:
-                self.stats.note_event()
-                self._note_reclaim(*self._fetch(
-                    self._reclaim_pass(np.int32(0), self._later)))
-            self.counters.steps += 1
-        return failed
-
-    def _fork_retry(self, src_ids: jax.Array, dst_ids: jax.Array,
-                    mask: jax.Array) -> np.ndarray:
-        """The fork op proper (COW, or eager when ``eager_fork``) with the
-        same reclaim-and-retry discipline as `step` — shared by `fork` and
-        `join`, which differ only in lineage bookkeeping."""
-        return self._retrying("repro.pool.fork", self._fork, src_ids,
-                              dst_ids, mask=mask)[0]
 
     def _current_lengths(self, seq_ids: np.ndarray) -> np.ndarray:
         tbl, has = vstore.current_read(self.st.mv, jnp.asarray(seq_ids))
@@ -667,33 +792,6 @@ class PagedKVEngine:
             self.dag.release(int(ids_np[i]))
         return failed
 
-    def reset(self, seq_ids: jax.Array, mask: jax.Array) -> np.ndarray:
-        """Recycle finished sequences' slots (empty table version); same
-        reclaim-and-retry discipline as `step`.  Returns failed[B] on the
-        host."""
-        with span("repro.engine.reset"):
-            failed, _ = self._retrying("repro.pool.reset", self._reset,
-                                       seq_ids, mask=mask, peak=False)
-            # The live count a reset leaves, as a program of its own and
-            # never read: the benchmark's trace test
-            # (tests/chipbench/test_chipbench_program_trace.py) expects
-            # ``jit_pool_live`` among an engine call's programs.  It costs a
-            # reset one small program and no host read.
-            self._live(self.st)
-        return failed
-
-    def reclaim(self, deficit: Optional[int] = None) -> int:
-        """Explicit GC pass (the engine-level ``gc_step``; API parity with
-        ``ShardedPagedKVEngine.reclaim``): chases the gate deficit, or an
-        explicit one — a large deficit forces the full cold-spill sweep,
-        and with ``ckpt_max`` armed the checkpoint-eviction post-pass runs
-        if the pool is still under pressure afterwards.  Counted as one
-        pressure event so the reclaims <= pressure_events invariant holds.
-        Returns pages freed."""
-        self.stats.note_event()
-        return self._note_reclaim(*self._fetch(self._reclaim_pass(
-            np.int32(0 if deficit is None else deficit), self._first)))
-
     def freed_pages(self) -> List[int]:
         """Drain the handles of pages recycled since the last call — exactly
         the loop the module docstring promises: a page appears here once its
@@ -706,56 +804,18 @@ class PagedKVEngine:
         return [int(p) for p in np.flatnonzero(self._pending(freed, free)
                                                & free)]
 
-    # -- durability (DESIGN.md §14) -------------------------------------
-    def checkpoint(self, directory: Union[str, os.PathLike,
-                                          CheckpointManager],
-                   step: Optional[int] = None) -> int:
-        """Durably checkpoint the whole engine: the paged-KV pytree (pages,
-        free bitmaps, page tables, the full MVState including the retire
-        ring and announce board) plus the host-side GC state (ReclaimStats,
-        fork DAG, pending freed-page handles).  Returns the manifest step.
-
-        Success *arms* the sole-survivor rule: ``ckpt_max`` advances to the
-        store clock, so every version written up to now is durable and an
-        idle sequence's sole surviving version may be evicted under pressure
-        — `restore` can always bring it back."""
-        mgr = (directory if isinstance(directory, CheckpointManager)
-               else CheckpointManager(os.fspath(directory)))
-        ts = int(self._fetch(self.st.mv.now))
-        step = ts if step is None else int(step)
-        extra = {
-            "stats": dataclasses.asdict(self.stats),
+    def _extras(self) -> Dict[str, Any]:
+        """The fork DAG and the freed pages not yet drained."""
+        return {
             "dag": self.dag.as_dict(),
             "freed_pages_pending": [int(p) for p in np.flatnonzero(
                 self._pending(*self._fetch((self._freed, self.st.free))))],
-            "ckpt_max": ts,
         }
-        mgr.save(step, self.st, extra=extra)
-        self.ckpt_max = ts
-        return step
 
-    def restore(self, directory: Union[str, os.PathLike, CheckpointManager],
-                step: Optional[int] = None) -> int:
-        """Inverse of `checkpoint`: replace the device pytree and replay the
-        host-side GC state (retire ring and announce board ride in the
-        pytree; stats/DAG/pending-frees come from the manifest extras), so
-        reclamation resumes exactly where the saved engine left off.
-        ``step=None`` restores the latest manifest."""
-        mgr = (directory if isinstance(directory, CheckpointManager)
-               else CheckpointManager(os.fspath(directory)))
-        if step is None:
-            step = mgr.latest_step()
-            if step is None:
-                raise FileNotFoundError(
-                    f"no checkpoint manifest under {mgr.dir!r}")
-        tree, extra = mgr.restore(int(step), like=self.st)
-        self.st = jax.tree_util.tree_map(jnp.asarray, tree)
-        self.stats = ReclaimStats(**extra.get("stats", {}))
+    def _load_extras(self, tree, extra: Dict[str, Any]) -> None:
         self.dag = ForkDAG.from_dict(extra.get("dag", {}))
         self._freed = self._drained(tree.free,
                                     extra.get("freed_pages_pending", []))
-        self.ckpt_max = int(extra.get("ckpt_max", -1))
-        return int(step)
 
     def pin(self, lane: int) -> int:
         with span("repro.snapshot.pin"):

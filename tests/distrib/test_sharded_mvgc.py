@@ -167,6 +167,78 @@ def test_sharded_trace_matches_single_host(policy):
                                           np.asarray(leaf_1))
 
 
+@pytest.mark.parametrize("policy", ["ebr", "slrt"])
+def test_sharded_engine_matches_single_host_engine(policy):
+    """The sharded engine runs the single-host engine's loop on every shard.
+    Driven with the same traffic on every host and no pins (the global LWM
+    is then the inert TS_MAX sentinel) through the trace of the fused-loop
+    test, every host ends each op in the single engine's state with its
+    failed lanes; the loop takes the same decisions (pressure events and
+    reclaim passes), and the page counts are the single engine's summed
+    over the hosts."""
+    from paged_trace import B as N, D, HKV, MP, PAGES, PS, trace
+    from repro.serve.engine import PagedKVEngine
+
+    hosts = 3
+    gc = GCConfig(policy=policy, versions_per_slot=2, reader_lanes=2)
+    one = PagedKVEngine(N, PAGES, PS, MP, HKV, D, gc=gc)
+    many = ShardedPagedKVEngine(hosts, N, PAGES, PS, MP, HKV, D, gc=gc)
+    ids = jnp.arange(N, dtype=jnp.int32)
+
+    def tiled(x):
+        x = jnp.asarray(x)
+        return jnp.broadcast_to(x[None], (hosts,) + x.shape)
+
+    rng = np.random.default_rng(["ebr", "slrt"].index(policy))
+    for n, (op, args) in enumerate(trace(rng, 80)):
+        what = f"{policy} op {n}: {op}{args}"
+        if op == "step":
+            kv = (jnp.full((N, HKV, D), args[1], jnp.float32)
+                  + ids[:, None, None])
+            mask = jnp.asarray(args[0])
+            want = one.step(ids, kv, kv, mask)
+            got = many.step(tiled(ids), tiled(kv), tiled(kv), tiled(mask))
+        elif op == "reset":
+            mask = jnp.asarray(args[0])
+            want = one.reset(ids, mask)
+            got = many.reset(tiled(ids), tiled(mask))
+        elif op == "fork":
+            src, dst = (jnp.asarray([x], jnp.int32) for x in args)
+            on = jnp.ones((1,), bool)
+            want = one.fork(src, dst, on)
+            got = many.fork(tiled(src), tiled(dst), tiled(on))
+        elif op == "reclaim":
+            want = one.reclaim(args[0])
+            got = many.reclaim(args[0])
+            assert got == hosts * want, what
+            got = want = np.zeros((), bool)
+        elif op == "arm":
+            # arms the checkpoint-eviction post-pass as `checkpoint` does,
+            # without writing one
+            one.ckpt_max = many.ckpt_max = int(one.st.mv.now)
+            got = want = np.zeros((), bool)
+        else:
+            continue                     # no pins
+        for h in range(hosts):
+            np.testing.assert_array_equal(got[h] if got.ndim else got,
+                                          want, err_msg=what)
+        for leaf_h, leaf_1 in zip(jax.tree.leaves(many.st),
+                                  jax.tree.leaves(one.st)):
+            for h in range(hosts):
+                np.testing.assert_array_equal(np.asarray(leaf_h[h]),
+                                              np.asarray(leaf_1),
+                                              err_msg=what)
+        assert (many.pressure_events, many.reclaims_triggered) == \
+            (one.pressure_events, one.reclaims_triggered), what
+        assert (many.pages_reclaimed, many.peak_pages, many.give_ups) == \
+            tuple(hosts * x for x in (one.pages_reclaimed, one.peak_pages,
+                                      one.give_ups)), what
+    # the trace reached reclaims, give-ups, forks and evictions
+    assert one.reclaims_triggered and one.give_ups and one.forks
+    assert many.forks == hosts * one.forks
+    assert many.stats.ckpt_evictions == hosts * one.stats.ckpt_evictions > 0
+
+
 # ---------------------------------------------------------------------------
 # global-LWM safety: a pin on one host protects snapshots on every host
 # ---------------------------------------------------------------------------

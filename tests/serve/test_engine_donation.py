@@ -1,7 +1,9 @@
-"""`PagedKVEngine` hands its state to the programs that return it: those
-programs alias the whole state (the page pool is updated in place, not
-copied), the programs that only read it alias nothing, and a state handed
-over is gone while pinned snapshots read as before."""
+"""The paged engines hand their state to the programs that return it:
+those programs alias the whole state (the page pool is updated in place,
+not copied), the programs that only read it alias nothing, and a state
+handed over is gone while pinned snapshots read as before.  The sharded
+engine runs the same programs on every shard, and donates as the
+single-host engine does."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,17 +12,28 @@ import pytest
 jax.config.update("jax_platform_name", "cpu")
 
 from repro.core.telemetry import GCConfig
+from repro.dist.mvgc import ShardedPagedKVEngine
 from repro.serve import engine as eng
 
 N = 4
+#: the sharded engine's host count
+H = 3
 
 
-def _engine():
+def _engine(kind="single"):
     # 4 sequences, 8 pages of 2 tokens, 2 versions per slab: a few appends
     # overflow the slabs, so reclaim passes run
-    return eng.PagedKVEngine(N, 8, 2, 4, 1, 4,
-                             gc=GCConfig(policy="slrt", versions_per_slot=2,
-                                         reader_lanes=2))
+    gc = GCConfig(policy="slrt", versions_per_slot=2, reader_lanes=2)
+    if kind == "sharded":
+        return ShardedPagedKVEngine(H, N, 8, 2, 4, 1, 4, gc=gc)
+    return eng.PagedKVEngine(N, 8, 2, 4, 1, 4, gc=gc)
+
+
+def _hosts(e, x):
+    """``x`` as engine ``e`` takes it: on every host for the sharded one."""
+    if isinstance(e, ShardedPagedKVEngine):
+        return jnp.broadcast_to(x[None], (H,) + x.shape)
+    return x
 
 
 def _ids():
@@ -44,14 +57,17 @@ def _alias_bytes(fn, *args):
 
 
 def _donating(e):
-    ids, kv, mask = _ids(), _kv(0), _mask(0)
+    ids, kv, mask = (_hosts(e, x) for x in (_ids(), _kv(0), _mask(0)))
+    pins = e._pins()
     return {
-        "_append": (e._append, (e.st, e._freed, e._first, ids, kv, kv, mask)),
-        "_fork": (e._fork, (e.st, e._freed, e._first, ids, ids, mask)),
-        "_reset": (e._reset, (e.st, e._freed, e._first, ids, mask)),
+        "_append": (e._append, (e.st, e._freed, e._first, ids, kv, kv, mask,
+                                pins)),
+        "_fork": (e._fork, (e.st, e._freed, e._first, ids, ids, mask,
+                            pins)),
+        "_reset": (e._reset, (e.st, e._freed, e._first, ids, mask, pins)),
         "_reclaim": (e._reclaim, (e.st, e._freed, e._first,
-                                 np.int32(1))),
-        "_evict": (e._evict, (e.st, e._freed, np.int32(0))),
+                                 np.int32(1), pins)),
+        "_evict": (e._evict, (e.st, e._freed, np.int32(0), pins)),
     }
 
 
@@ -63,14 +79,15 @@ def _reading(e):
     }
 
 
+@pytest.mark.parametrize("kind", ["single", "sharded"])
 @pytest.mark.parametrize("name", ["_append", "_fork", "_reset", "_reclaim",
                                   "_evict"])
-def test_state_returning_programs_alias_the_pool(name):
-    """The pool and the freed-since-drain bits are both updated in
-    place."""
-    e = _engine()
+def test_state_returning_programs_alias_the_pool(name, kind):
+    """The pool and the freed-since-drain bits (the sharded engine keeps
+    none) are both updated in place."""
+    e = _engine(kind)
     fn, args = _donating(e)[name]
-    bits = sum(x.nbytes for x in e._freed)
+    bits = sum(x.nbytes for x in jax.tree.leaves(e._freed))
     assert _alias_bytes(fn, *args) >= _pool_bytes(e) + bits
 
 
@@ -81,16 +98,20 @@ def test_reading_programs_alias_nothing(name):
     assert _alias_bytes(fn, *args) == 0
 
 
-def test_step_consumes_the_state_it_was_given():
-    e = _engine()
-    before, freed = e.st, e._freed
-    e.step(_ids(), _kv(1), _kv(1), _mask(0, 1, 2, 3))
+@pytest.mark.parametrize("kind", ["single", "sharded"])
+def test_step_consumes_the_state_it_was_given(kind):
+    e = _engine(kind)
+    before, freed = e.st, jax.tree.leaves(e._freed)
+    e.step(*(_hosts(e, x) for x in (_ids(), _kv(1), _kv(1),
+                                    _mask(0, 1, 2, 3))))
     assert before.k_pages.is_deleted() and before.v_pages.is_deleted()
     assert all(x.is_deleted() for x in freed)
-    assert not any(x.is_deleted() for x in e._freed)
+    assert not any(x.is_deleted() for x in jax.tree.leaves(e._freed))
     assert not e.st.k_pages.is_deleted()
-    # the new pool holds the four tokens appended, head_dim 4 each
-    assert float(jnp.abs(e.st.k_pages).sum()) == 16.0
+    # the new pool holds the four tokens appended, head_dim 4 each, on
+    # every host
+    hosts = H if kind == "sharded" else 1
+    assert float(jnp.abs(e.st.k_pages).sum()) == 16.0 * hosts
 
 
 def test_reset_and_reclaim_consume_the_state():

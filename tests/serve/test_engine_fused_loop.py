@@ -17,13 +17,11 @@ import pytest
 
 jax.config.update("jax_platform_name", "cpu")
 
+from paged_trace import B, D, HKV, MP, PAGES, PS
+from paged_trace import trace as _trace
 from repro.core.telemetry import GCConfig, ReclaimStats
 from repro.mvkv import paged
 from repro.serve import engine as eng
-
-# the geometry of test_engine_telemetry._paged: 4 sequences of at most 4
-# pages of 2 tokens, a pool of 8 pages, 2 versions per slab
-B, PAGES, PS, MP, HKV, D = 4, 8, 2, 4, 1, 4
 
 
 class Oracle:
@@ -129,29 +127,6 @@ class Oracle:
     def freed_pages(self):
         out, self.freed = self.freed, []
         return out
-
-
-def _trace(rng, n_ops):
-    """(op, args) pairs; masks, values and lanes drawn from ``rng``."""
-    ops = []
-    for i in range(n_ops):
-        op = rng.choice(["step"] * 10 + ["reset", "fork"] * 2 + ["pin",
-                        "unpin", "reclaim"] + ["arm"] * (i > n_ops // 2))
-        if op == "step":
-            ops.append((op, (rng.random(B) < 0.8, float(i + 1))))
-        elif op == "reset":
-            ops.append((op, (rng.random(B) < 0.4,)))
-        elif op == "fork":
-            src, dst = rng.choice(B, size=2, replace=False)
-            ops.append((op, (int(src), int(dst))))
-        elif op in ("pin", "unpin"):
-            ops.append((op, (int(rng.integers(2)),)))
-        elif op == "reclaim":
-            ops.append((op, (None if rng.random() < 0.5
-                             else int(rng.integers(1, 2 * PAGES)),)))
-        else:
-            ops.append((op, ()))
-    return ops
 
 
 def _assert_same(e, o, got, want, what):

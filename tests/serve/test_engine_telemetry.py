@@ -33,13 +33,15 @@ def spans(monkeypatch):
     return seen
 
 
-def _paged(num_pages=8, versions=2):
+def _paged(num_pages=8, versions=2, hosts=None):
     # 4 sequences, pages of 2 tokens, 2 versions per slab: appends soon
-    # overflow the slabs and the pool, so reclaim passes run
-    return eng.PagedKVEngine(4, num_pages, 2, 4, 1, 4,
-                             gc=GCConfig(policy="slrt",
-                                         versions_per_slot=versions,
-                                         reader_lanes=2))
+    # overflow the slabs and the pool, so reclaim passes run; with
+    # ``hosts``, the sharded engine with that many
+    gc = GCConfig(policy="slrt", versions_per_slot=versions, reader_lanes=2)
+    if hosts:
+        from repro.dist.mvgc import ShardedPagedKVEngine
+        return ShardedPagedKVEngine(hosts, 4, num_pages, 2, 4, 1, 4, gc=gc)
+    return eng.PagedKVEngine(4, num_pages, 2, 4, 1, 4, gc=gc)
 
 
 def _serve():
@@ -96,20 +98,28 @@ def _count_programs(e):
     return runs
 
 
-def test_paged_step_reads_failed_once_per_round(spans):
+@pytest.mark.parametrize("hosts", [None, 3], ids=["single", "sharded"])
+def test_paged_step_reads_failed_once_per_round(spans, hosts):
     """With no reclaim and no watermark crossed, a step runs one program,
     the append, and reads its failed lanes, live count and gate in one
-    fetch: one sync."""
-    e = _paged(num_pages=64, versions=8)
+    fetch: one sync.  The sharded engine runs the same loop, and reads
+    once more before the append, for the global LWM it passes every
+    shard."""
+    e = _paged(num_pages=64, versions=8, hosts=hosts)
     runs = _count_programs(e)
     ids = jnp.arange(4, dtype=jnp.int32)
     kv = jnp.ones((4, 1, 4), jnp.float32)
-    failed = e.step(ids, kv, kv, jnp.ones((4,), bool))
+    on = jnp.ones((4,), bool)
+    if hosts:
+        ids, kv, on = (jnp.broadcast_to(x[None], (hosts,) + x.shape)
+                       for x in (ids, kv, on))
+    failed = e.step(ids, kv, kv, on)
     assert isinstance(failed, np.ndarray) and not failed.any()
+    assert failed.shape == on.shape
     assert e.stats.reclaims_triggered == 0
-    assert e.counters.host_syncs == 1
+    assert e.counters.host_syncs == (2 if hosts else 1)
     assert runs == {"_append": 1}
-    assert e.stats.peak_live == 4
+    assert e.stats.peak_live == 4 * (hosts or 1)
 
 
 def test_paged_reclaim_step_reads_once_per_round(spans):
